@@ -40,6 +40,7 @@ _SCRIPT = textwrap.dedent("""
     dt = time.time() - t0
     m = solver.metrics(solver.run(st, passes=1))
     print(json.dumps({"p": len(jax.devices()), "seconds": dt,
+                      "platform": jax.devices()[0].platform,
                       "viol": m["max_violation"]}))
 """)
 
@@ -47,6 +48,9 @@ _SCRIPT = textwrap.dedent("""
 def run() -> list[dict]:
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # Forced host devices, never the accelerator: a chip belongs to one
+    # process, and the benchmarks.run parent may hold it.
+    env["JAX_PLATFORMS"] = "cpu"
     rows = []
     base = None
     for p in COUNTS:
@@ -64,7 +68,8 @@ def run() -> list[dict]:
         rows.append(dict(
             name=f"fig6/p{p}",
             us_per_call=d["seconds"] / PASSES * 1e6,
-            derived=f"rel_time={d['seconds']/base:.2f} (1 host core; "
+            derived=f"platform={d['platform']} "
+                    f"rel_time={d['seconds']/base:.2f} (1 host core; "
                     f"per-device work ∝ n³/p, psum ∝ n² per diagonal)",
         ))
     return rows

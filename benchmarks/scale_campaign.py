@@ -134,7 +134,7 @@ _WORKER = textwrap.dedent("""
         th.join()
         ckpt_lib.wait_pending()
         print("ROW " + json.dumps(dict(
-            devices=p, n=n,
+            devices=p, n=n, platform=jax.devices()[0].platform,
             pass_ms=round(pass_ms, 3), probe_ms=round(probe_ms, 3),
             peak_live_bytes=int(mem_b), mem_source=mem_src,
             dual_slab_bytes_per_device=cfg["model_bytes"][str(n)],
@@ -154,6 +154,9 @@ def _campaign(counts, budget_mb, cap, *, buckets=3, block_c=None,
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.pop("XLA_FLAGS", None)  # each worker pins its own device count
+    # Forced host devices, never the accelerator: a chip belongs to one
+    # process, and the parent may hold it.
+    env["JAX_PLATFORMS"] = "cpu"
     rows = []
     for p in counts:
         ladder = feasible_ladder(p, budget_mb, cap=cap)
@@ -184,7 +187,8 @@ def _campaign(counts, budget_mb, cap, *, buckets=3, block_c=None,
         ]
         top = per_n[-1]
         rows.append(dict(
-            devices=p, largest_n=top["n"], pass_ms=top["pass_ms"],
+            devices=p, platform=top["platform"], largest_n=top["n"],
+            pass_ms=top["pass_ms"],
             probe_ms=top["probe_ms"],
             peak_live_bytes=top["peak_live_bytes"],
             viol=top["viol"], gap=top["gap"], converged=top["converged"],
@@ -206,7 +210,8 @@ def _report(rows, mode, budget_mb, json_path):
                 f"pass_ms={r['pass_ms']:.1f} probe_ms={r['probe_ms']:.1f} "
                 f"peak_mb={r['peak_live_bytes'] / 1e6:.1f} "
                 f"snapshot_block_ms={r['snapshot_block_ms']:.1f} "
-                f"snapshot_dispatch_ms={r['snapshot_dispatch_ms']:.1f}"
+                f"snapshot_dispatch_ms={r['snapshot_dispatch_ms']:.1f} "
+                f"platform={r['platform']}"
             )
         print(
             f"certificate p={row['devices']} largest_n={row['largest_n']} "
@@ -240,7 +245,7 @@ def run() -> list[dict]:
             name=f"scale/p{row['devices']}",
             us_per_call=row["pass_ms"] * 1e3,
             derived=(
-                f"largest_n={row['largest_n']} "
+                f"platform={row['platform']} largest_n={row['largest_n']} "
                 f"probe_ms={row['probe_ms']:.1f} "
                 f"peak_mb={row['peak_live_bytes'] / 1e6:.1f} "
                 f"converged={row['converged']} "

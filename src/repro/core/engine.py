@@ -27,10 +27,11 @@ Subclass contract: provide ``p`` (MetricQP), ``n``, ``dtype``, ``layout``,
 ``_w``/``_d``/``_wf``/``_mask`` device constants, ``init_state()`` and
 ``_one_pass(state) -> state``; optionally ``fused`` / ``probe_every`` /
 ``_pass_fn`` (the runner knobs — defaults True / 1 / a fresh jit of
-``_one_pass``), and overrides for ``_triangle_violation`` (the sharded
-solver routes it through a psum-max, the kernel solver through the Pallas
-apex-block kernel) and ``_put_slab`` (device placement of imported dual
-slabs).
+``_one_pass``), ``_staged_arrays`` (the staged device arrays the pass
+reads through ``_staged_view``), and overrides for ``_triangle_violation``
+(the sharded solver routes it through a psum-max, the kernel solver
+through the Pallas apex-block kernel) and ``_put_slab`` (device placement
+of imported dual slabs).
 
 The float64 numpy path in `core/convergence.py` stays as the oracle the
 engine is property-tested against (tests/test_engine.py, 1e-10).
@@ -257,6 +258,36 @@ class SolverRuntime:
             for m in sched.slab_valid_masks(self.layout, self._n_real)
         ]
 
+    # ---------------------------------------------------- staged operands
+    # Pass-invariant device arrays ``_one_pass`` reads besides the state
+    # (staged geometry, gains, masks). An array a traced function closes
+    # over is embedded in the program as a constant (gigabytes of HLO at
+    # n ~ 10^3), so the jitted runners take them as an operand instead.
+    _staged_tracer = None
+
+    def _staged_arrays(self):
+        """Subclass hook: the staged array pytree (None: nothing staged)."""
+        return None
+
+    def _staged_view(self):
+        """The staged arrays as traced code must read them: the operand
+        inside a ``_jit_staged`` program, the arrays themselves outside."""
+        view = self._staged_tracer
+        return self._staged_arrays() if view is None else view
+
+    def _jit_staged(self, fn):
+        """``jax.jit(fn)``, with the staged arrays passed as an operand."""
+
+        def traced(staged, *args):
+            outer, self._staged_tracer = self._staged_tracer, staged
+            try:
+                return fn(*args)
+            finally:
+                self._staged_tracer = outer
+
+        jitted = jax.jit(traced)
+        return lambda *args: jitted(self._staged_arrays(), *args)
+
     @functools.cached_property
     def _engine_cache(self) -> dict:
         return {"report": {}, "until": {}, "probe": None}
@@ -425,7 +456,7 @@ class SolverRuntime:
                     body, st, jnp.arange(passes, dtype=jnp.int32)
                 )
 
-            fn = cache[passes] = jax.jit(multi)
+            fn = cache[passes] = self._jit_staged(multi)
         return fn
 
     def run(self, state=None, passes: int = 1):
@@ -521,7 +552,7 @@ class SolverRuntime:
                     cond, body, (st, inf, inf, inf, inf, resbuf0, k0, div0)
                 )
 
-            fn = cache[key] = jax.jit(runner)
+            fn = cache[key] = self._jit_staged(runner)
         return fn
 
     def _probe_fn(self):

@@ -26,27 +26,12 @@ problem data.
 from __future__ import annotations
 
 import dataclasses
-import inspect
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.problems import MetricQP
-
-try:  # jax >= 0.5 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# Replication-check kwarg of shard_map (renamed check_rep -> check_vma
-# across jax versions). The kernel-backed sharded probe must disable it:
-# pallas_call carries no replication rule, same as the sharded sweep.
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 __all__ = [
     "DeviceProblem",
@@ -227,7 +212,7 @@ def triangle_violation_sharded(xs, mesh, axis: str = "solver",
         v = jax.lax.map(lambda c: _apex_block_max(xs_rep, c, n_live), blocks)
         return jax.lax.pmax(jnp.max(v), axis)
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P()
     )(xs, cs)
 
@@ -276,9 +261,10 @@ def triangle_violation_sharded_kernel(xs, mesh, axis: str = "solver",
         )
         return jax.lax.pmax(v, axis)
 
-    return _shard_map(
+    # pallas_call carries no replication rule, same as the sharded sweep.
+    return jax.shard_map(
         local, mesh=mesh, in_specs=(P(), P(axis)), out_specs=P(),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )(xs, xa)
 
 

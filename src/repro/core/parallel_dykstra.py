@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -116,8 +115,8 @@ class ParallelSolver(SolverRuntime):
       problem: the MetricQP instance.
       dtype: compute dtype (float32 default; float64 if x64 enabled).
       use_kernel: use the Pallas whole-bucket megakernel (interpret=True on
-        CPU) instead of the pure-jnp fused reference; with ``fused=False``,
-        the first-generation per-diagonal kernel.
+        CPU) instead of the pure-jnp fused reference. Requires
+        ``fused=True``.
       bucket_diagonals: group diagonals into T-size buckets to cut padding
         waste (beyond-paper optimization; see EXPERIMENTS.md §Solver-perf).
       fused: fused-pass execution (DESIGN.md §4, default) — static staging
@@ -160,6 +159,12 @@ class ParallelSolver(SolverRuntime):
         self.probe_every = max(1, int(probe_every))
         self.sweep_unroll = max(1, int(sweep_unroll))
         self.bucket_diagonals = max(1, int(bucket_diagonals))
+        if use_kernel and not fused:
+            raise ValueError(
+                "use_kernel=True requires fused=True: the gen-1 "
+                "per-diagonal kernel is test-oracle only, so the legacy "
+                "path has no kernel sweep."
+            )
         self.layout = sched.build_layout(
             self.n,
             num_buckets=self.bucket_diagonals,
@@ -175,7 +180,7 @@ class ParallelSolver(SolverRuntime):
             self.n, self.n_real if self.n_real < self.n else None
         )
         self._buckets = self._stage_buckets()
-        self._pass_fn = jax.jit(self._one_pass)
+        self._pass_fn = self._jit_staged(self._one_pass)
 
     def _stage_buckets(self) -> list[dict]:
         """Device-resident per-bucket work arrays (procs=1 → unit device
@@ -233,6 +238,10 @@ class ParallelSolver(SolverRuntime):
             )
         return buckets
 
+    def _staged_arrays(self) -> list[dict]:
+        return [{k: v for k, v in b.items() if k != "T"}
+                for b in self._buckets]
+
     @property
     def staged_buckets(self) -> list[dict]:
         """Public view of the per-bucket staged work arrays, in schedule
@@ -283,17 +292,7 @@ class ParallelSolver(SolverRuntime):
 
     # ------------------------------------------------------------- one pass
     def _sweep_fn(self):
-        if self.use_kernel:
-            # Gen-1 per-diagonal kernel is test-oracle-only since PR 6;
-            # the kernel-backed legacy body would silently mix kernel
-            # generations, so fall back loudly to the jnp sweep.
-            warnings.warn(
-                "use_kernel=True with fused=False has no kernel path: the "
-                "gen-1 per-diagonal kernel is demoted to test-oracle "
-                "status; running the jnp reference sweep instead. Use "
-                "fused=True (default) for the gen-3 megakernel.",
-                stacklevel=3,
-            )
+        # Legacy (fused=False) path only, which never runs a kernel.
         from repro.kernels.metric_project import ref as kref
 
         return kref.sweep_ref_slab
@@ -341,10 +340,14 @@ class ParallelSolver(SolverRuntime):
         """All triangle constraints of one pass: one fused bucket program
         per bucket (default), or the legacy per-diagonal scan."""
         new_yd = []
+        buckets = [
+            {"T": b["T"]} | s
+            for b, s in zip(self._buckets, self._staged_view())
+        ]
         if self.fused and self.use_kernel:
             from repro.kernels.metric_project import ops as kops
 
-            for b, yb in zip(self._buckets, yd):
+            for b, yb in zip(buckets, yd):
                 x, nyb = kops.fused_bucket_pass(
                     x, yb, b, unroll=self.sweep_unroll
                 )
@@ -352,13 +355,13 @@ class ParallelSolver(SolverRuntime):
         elif self.fused:
             from repro.kernels.metric_project import ref as kref
 
-            for b, yb in zip(self._buckets, yd):
+            for b, yb in zip(buckets, yd):
                 x, nyb = kref.fused_bucket_pass_ref(
                     x, yb, b, unroll=self.sweep_unroll
                 )
                 new_yd.append(nyb)
         else:
-            for b, yb in zip(self._buckets, yd):
+            for b, yb in zip(buckets, yd):
                 body = functools.partial(self._diagonal_body, T=b["T"])
                 xs = {key: b[key] for key in ("i", "k", "s", "i2", "k2", "s2")}
                 x, nyb = jax.lax.scan(body, x, xs | {"y": yb})

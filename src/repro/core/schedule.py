@@ -44,6 +44,7 @@ __all__ = [
     "folded_geometry_np",
     "device_assignment",
     "n_triplets",
+    "slab_dims",
     "slab_valid_masks",
 ]
 
@@ -293,6 +294,51 @@ class ScheduleLayout:
         return [b.slab_shape for b in self.buckets]
 
 
+def _fold(d: Diagonal):
+    """Fold one diagonal: lane f = (set f, set C-1-f); the middle set of
+    an odd diagonal rides alone. Paired sizes sum to a constant, so lane
+    heights are near-uniform (see module comment)."""
+    C = d.num_sets
+    F = (C + 1) // 2
+    cA = np.arange(F)
+    cB = C - 1 - cA
+    iA, kA = d.i[cA], d.k[cA]
+    iB = np.where(cB > cA, d.i[cB], -1)
+    kB = np.where(cB > cA, d.k[cB], -1)
+    return iA, kA, iB, kB
+
+
+def _bucket_dims(folds, procs: int, pad_sets_to: int | None):
+    """(T, Cl) of one bucket's folded lanes."""
+    T = max(
+        int(((kA - iA - 1) + np.where(iB >= 0, kB - iB - 1, 0)).max())
+        for iA, kA, iB, kB in folds
+    )
+    Cl = max(-(-len(f[0]) // procs) for f in folds)
+    if pad_sets_to:
+        Cl = ((Cl + pad_sets_to - 1) // pad_sets_to) * pad_sets_to
+    return T, Cl
+
+
+def slab_dims(
+    n: int,
+    num_buckets: int = 1,
+    procs: int = 1,
+    pad_sets_to: int | None = None,
+) -> list[tuple[int, int, int]]:
+    """``(D, T, Cl)`` of every bucket slab of ``build_layout(n, ...)``,
+    without its conversion maps — cheap at any n (shape planning and
+    compile checks)."""
+    diags = diagonal_list(n)
+    groups = np.array_split(np.arange(len(diags)), max(1, int(num_buckets)))
+    return [
+        (len(g),) + _bucket_dims(
+            [_fold(diags[r]) for r in g], procs, pad_sets_to
+        )
+        for g in groups if len(g)
+    ]
+
+
 @functools.lru_cache(maxsize=32)
 def build_layout(
     n: int,
@@ -316,29 +362,9 @@ def build_layout(
     for g in groups:
         if len(g) == 0:
             continue
-        ds = [diags[r] for r in g]
-        D = len(ds)
-        # Fold: lane f = (set f, set C-1-f); the middle set of an odd
-        # diagonal rides alone. Paired sizes sum to a constant, so lane
-        # heights are near-uniform (see module comment).
-        folds = []
-        for d in ds:
-            C = d.num_sets
-            F = (C + 1) // 2
-            cA = np.arange(F)
-            cB = C - 1 - cA
-            iA, kA = d.i[cA], d.k[cA]
-            iB = np.where(cB > cA, d.i[cB], -1)
-            kB = np.where(cB > cA, d.k[cB], -1)
-            folds.append((iA, kA, iB, kB))
-        heights = [
-            int(((kA - iA - 1) + np.where(iB >= 0, kB - iB - 1, 0)).max())
-            for iA, kA, iB, kB in folds
-        ]
-        T = max(heights)
-        Cl = max(-(-len(f[0]) // procs) for f in folds)
-        if pad_sets_to:
-            Cl = ((Cl + pad_sets_to - 1) // pad_sets_to) * pad_sets_to
+        D = len(g)
+        folds = [_fold(diags[r]) for r in g]
+        T, Cl = _bucket_dims(folds, procs, pad_sets_to)
         arrs = {
             name: np.full((procs, D, Cl), -1, dtype=np.int32)
             for name in ("i", "k", "i2", "k2")

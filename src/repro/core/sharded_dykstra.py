@@ -55,21 +55,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
-
-# The replication-check kwarg was renamed check_rep -> check_vma in newer
-# jax; pick whichever the selected shard_map accepts.
-import inspect as _inspect
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(shard_map).parameters
-    else "check_rep"
-)
-
 from repro.core import metrics_device, schedule as sched
 from repro.core.engine import SolverRuntime
 from repro.core.parallel_dykstra import folded_geometry
@@ -143,14 +128,10 @@ class ShardedSolver(SolverRuntime):
                 "deltas host-side and has no kernel path."
             )
         if use_kernel and not fused:
-            import warnings
-
-            warnings.warn(
-                "use_kernel=True with fused=False has no kernel path: the "
-                "gen-1 per-diagonal kernel is demoted to test-oracle "
-                "status (PR 6); running the legacy jnp sweep instead. Use "
-                "fused=True (default) for the gen-3 megakernel.",
-                stacklevel=2,
+            raise ValueError(
+                "use_kernel=True requires fused=True: the gen-1 "
+                "per-diagonal kernel is test-oracle only, so the legacy "
+                "path has no kernel sweep."
             )
         self.p = problem
         self.n = problem.n
@@ -230,7 +211,7 @@ class ShardedSolver(SolverRuntime):
                     "w_ikp": put(sb.w_ikp),
                 }
             self._work_dev.append(work)
-        self._pass_fn = jax.jit(self._one_pass)
+        self._pass_fn = self._jit_staged(self._one_pass)
 
     # ------------------------------------------------------------------ state
     def init_state(self) -> ShardedState:
@@ -388,20 +369,24 @@ class ShardedSolver(SolverRuntime):
         x, new_yd = jax.lax.scan(diag_body, x, (work, yd_b))
         return x, new_yd[None]  # restore the local device axis for out_specs
 
+    def _staged_arrays(self) -> list[dict]:
+        return [{k: v for k, v in w.items() if k != "T"}
+                for w in self._work_dev]
+
     def _one_pass(self, st: ShardedState) -> ShardedState:
         x = st.x
         new_yd = []
-        for b, work in zip(st.yd, self._work_dev):
+        for b, work, arrays in zip(st.yd, self._work_dev,
+                                   self._staged_view()):
             fn = functools.partial(self._device_bucket, T=work["T"])
-            arrays = {key: val for key, val in work.items() if key != "T"}
-            x, yb = shard_map(
+            x, yb = jax.shard_map(
                 fn,
                 mesh=self.mesh,
                 in_specs=(P(), P(AXIS), P(AXIS)),
                 out_specs=(P(), P(AXIS)),
                 # pallas_call has no replication rule; the per-diagonal psum
                 # makes x replicated by construction.
-                **{_CHECK_KW: not self.use_kernel},
+                check_vma=not self.use_kernel,
             )(x, b, arrays)
             new_yd.append(yb)
         f, ypair, ybox = st.f, st.ypair, st.ybox

@@ -1,13 +1,13 @@
 """Pallas TPU megakernel, third generation: one batch- and shard-aware
-fused-pass kernel behind every sweep path (DESIGN.md §10).
+fused-pass engine behind every sweep path (DESIGN.md §10).
 
 The second-generation kernel (DESIGN.md §4, superseded) fused a whole
 bucket into one ``pallas_call`` but baked the staged projection gains and
 act masks into the trace as constants and served exactly one instance per
 launch. Gen-3 changes the contract, not the math:
 
-  * **Leading instance grid axis**: the grid is ``(B, D, lane blocks)`` —
-    a whole serve bucket of B padded instances runs as ONE ``pallas_call``.
+  * **Leading instance axis**: a whole serve bucket of B padded instances
+    runs as ONE bucket program.
   * **Weights as runtime operands**: the staged gains ``g_row / g_col /
     g_sel / dinv`` and the per-instance (ghost-aware) ``act`` masks arrive
     with a leading batch axis as ordinary operands, never trace constants —
@@ -16,41 +16,37 @@ launch. Gen-3 changes the contract, not the math:
     Only the lane tables, the ``seg`` masks and the folded geometry — pure
     functions of the bucket shape — stay shared.
   * **Delta-output mode** (``out_delta=True``, single diagonal): instead of
-    updating X in place the kernel scatters the act-masked deltas into a
+    updating X in place the engine scatters the act-masked deltas into a
     zero buffer — exactly the per-device delta matrix the sharded solver
     psum-merges per diagonal (bitwise-equal to the jnp fused path's
     scatter, because both scatter the same ``where(act, new - old, 0)``
     values into zeros).
 
-Two staging engines implement the same contract:
+Both staging engines walk the bucket's diagonals in one loop, carrying
+the dual slab and updating it in place. Per diagonal they gather the folded X row/column/carry slices in XLA
+(the indexing ``ref.fused_bucket_pass_ref`` uses), sweep them, and
+scatter the act-masked deltas back in XLA. They differ only in the sweep:
 
-  * ``mode="dma"`` (TPU production): the gen-2 per-lane body — X resident
-    in VMEM per instance via a constant-index output block, per-lane
-    dynamic-slice gather/scatter driven by the scalar-prefetched lane
-    tables, zero-delta-tail exactness (the in-kernel restatement of the
-    paper's conflict-freedom argument, §III.A). The batch axis is squeezed
-    out of every BlockSpec (``None`` leading block dim), so the body is
-    the gen-2 body verbatim; instance b's X is fetched at grid step
-    (b, 0, 0) and written back once per instance.
-  * ``mode="vector"`` (CPU / interpret default): per instance, one
-    ``lax.scan`` over the bucket's diagonals of the jnp fused reference's
-    per-diagonal body — gather, ``ref.fused_diag_sweep``, scatter —
-    vmapped over B, using the folded-geometry operand. When the lane axis
-    fits one block (every production bucket) this dispatches XLA-native
-    (``_vector_bucket_pass``): the pallas grid would be a single step
-    whose interpret wrapper only adds whole-buffer copies around the
-    identical body, so the batched kernel path costs what the vmapped
-    reference costs. The multi-block fallback keeps the pallas grid (one
-    diagonal per step); interpret mode executes kernels as traced jnp,
-    where the dma engine's per-lane ``fori_loop`` staging is
-    dispatch-bound (~20x slower than the vectorized gathers).
+  * ``mode="tpu"`` (TPU production, the only engine compiled with
+    ``interpret=False``): the sweep is a Pallas kernel (``_sweep_kernel``)
+    over ``(T, block_c)`` lane tiles of the gathered slices, dual slab,
+    gains and masks; grid ``(B, lane blocks)``. Step t reads and writes
+    whole rows ``ref[pl.ds(t, 1), :]`` of lane-aligned blocks, so every
+    VMEM access is tile-aligned. That is the shape Mosaic accepts: the
+    in-kernel per-lane gathers of the earlier engine (windows at dynamic
+    lane/sublane offsets, single-row dynamic stores, value
+    ``dynamic_slice``) are all refused by the TPU compiler (DESIGN.md §10).
+    Nothing of X lives in VMEM and no lane table lives in SMEM.
+  * ``mode="vector"`` (CPU / interpret only): the sweep is
+    ``ref.fused_diag_sweep`` vmapped over B. With one lane block (every
+    production bucket) the bucket program is plain XLA; with several it
+    keeps a pallas grid of one diagonal per step (``_fused_kernel_vector``)
+    in interpret mode.
 
-VMEM budget (dma mode, per grid step): (n+T+1)^2 * 4 (one instance's
-resident X) + 9*T*block_c * 4 (dual + gain + mask blocks) + 6*T*block_c
-* 4 (scratch) — identical to gen-2, because the batch axis contributes
-nothing resident: at n = 96, T = 47, block_c = 128 that is ~0.4 MiB +
-~2.9 MiB, comfortably inside a ~16 MiB v5e VMEM budget for any B. The
-vector engine holds B*(n+T+1)^2 floats and is CPU-only by construction.
+VMEM budget (tpu mode, per grid step): 16 ``(T, block_c)`` tiles (11 in,
+5 out), double-buffered by the grid pipeline — 32·T·block_c·4 bytes, so
+12.6 MiB at T = 768, block_c = 128. The vector engine holds
+B·n² floats and is CPU-only by construction.
 
 Exactness note shared by both engines: every scatter outside a lane's
 active cells adds an exact 0.0 (act-masked deltas; carry deltas guarded
@@ -73,173 +69,127 @@ from repro.kernels.metric_project.ref import fused_diag_sweep, fused_step
 __all__ = ["fused_bucket_pass_pallas"]
 
 
-def _fused_kernel_dma(
-    lanes_ref,  # (6, D, Cp) int32 scalar-prefetch: i1, k1, s1, i2, k2, s2
-    x_ref,      # (np, np) this instance's iterate (batch axis squeezed)
-    y_ref,      # (1, 3, T, Cb) dual block of this (instance, diagonal, block)
-    grow_ref,   # (1, T, Cb) per-instance staged gains (runtime operands)
+def _sweep_kernel(
+    row_ref,    # (T, Cb) folded row slices x[i, j]
+    col_ref,    # (T, Cb) folded column slices x[j, k]
+    xik_ref,    # (2, Cb) the two folded x_ik carries
+    y_ref,      # (3, T, Cb) dual block
+    grow_ref,   # (T, Cb) staged gains (runtime operands)
     gcol_ref,
     gsel_ref,
     dinv_ref,
-    act_ref,    # (1, T, Cb) int8 per-instance (ghost-aware) step mask
-    seg_ref,    # (1, T, Cb) int8 shared segment mask
-    ox_ref,     # (np, np) resident working buffer: X, or the delta matrix
-    oy_ref,     # (1, 3, T, Cb)
-    rowS,       # (Cb, 2T) scratch: folded row slices, then row deltas
-    colS,       # (Cb, 2T) scratch: folded col slices, then col deltas
-    dR,         # (T, Cb) scratch: act-masked row deltas (sweep layout)
-    dC,         # (T, Cb) scratch: act-masked col deltas
+    act_ref,    # (T, Cb) int32 per-instance (ghost-aware) step mask
+    seg_ref,    # (T, Cb) int32 shared segment mask
+    orow_ref,   # (T, Cb) swept row slices
+    ocol_ref,   # (T, Cb) swept column slices
+    oxik_ref,   # (2, Cb) final carries
+    oy_ref,     # (3, T, Cb) new duals (aliases y_ref when in place)
     *,
     T: int,
-    block_c: int,
-    out_delta: bool,
+    unroll: int,
 ):
-    d = pl.program_id(1)
-    cb = pl.program_id(2)
-    # Constant index components must match the int32 traced starts even
-    # under jax_enable_x64 (python ints would promote to int64).
-    i32 = lambda v: jnp.asarray(v, jnp.int32)
-
-    # First grid step of every instance: x_ref/ox_ref map fresh blocks
-    # whenever the batch index advances, so this fires once per instance.
-    @pl.when((d == 0) & (cb == 0))
-    def _init_x():
-        ox_ref[...] = (
-            jnp.zeros(ox_ref.shape, ox_ref.dtype) if out_delta
-            else x_ref[...]
-        )
-
-    # Delta mode reads the pristine X (single diagonal: every gather
-    # precedes every scatter semantically); in-place mode reads the
-    # resident buffer, which carries earlier diagonals' updates.
-    src_ref = x_ref if out_delta else ox_ref
-    dt = x_ref.dtype
-    col0 = cb * block_c
-
-    def lane_scalars(c):
-        i1 = lanes_ref[0, d, col0 + c]
-        k1 = lanes_ref[1, d, col0 + c]
-        s1 = lanes_ref[2, d, col0 + c]
-        i2 = lanes_ref[3, d, col0 + c]
-        k2 = lanes_ref[4, d, col0 + c]
-        s2 = lanes_ref[5, d, col0 + c]
-        # Padding lanes carry -1; clamp to cell (0, 0) / row 0 — their
-        # deltas are exactly zero, so the clamped windows only ever add 0.
-        r1 = jnp.maximum(i1, 0)
-        q1 = jnp.maximum(k1, 0)
-        r2 = jnp.maximum(i2, 0)
-        q2 = jnp.maximum(k2, 0)
-        return s1, s2, r1, q1, r2, q2
-
-    # ---- gather: stage folded row/col slices of X and the two carries.
-    # Lane c, segment A occupies folded steps [0, s1) (slices from (i1, k1)),
-    # segment B is appended at [s1, s1 + s2) — writing the fixed-length-T
-    # segment-B slice at dynamic offset s1 performs the fold in-place.
-    def stage(c, xik):
-        c = i32(c)
-        s1, s2, r1, q1, r2, q2 = lane_scalars(c)
-        rowA = pl.load(src_ref, (pl.ds(r1, 1), pl.ds(r1 + 1, T)))
-        pl.store(rowS, (pl.ds(c, 1), pl.ds(i32(0), T)), rowA)
-        rowB = pl.load(src_ref, (pl.ds(r2, 1), pl.ds(r2 + 1, T)))
-        pl.store(rowS, (pl.ds(c, 1), pl.ds(s1, T)), rowB)
-        colA = pl.load(src_ref, (pl.ds(r1 + 1, T), pl.ds(q1, 1)))
-        pl.store(colS, (pl.ds(c, 1), pl.ds(i32(0), T)), colA.reshape(1, T))
-        colB = pl.load(src_ref, (pl.ds(r2 + 1, T), pl.ds(q2, 1)))
-        pl.store(colS, (pl.ds(c, 1), pl.ds(s1, T)), colB.reshape(1, T))
-        xa = pl.load(src_ref, (pl.ds(r1, 1), pl.ds(q1, 1)))
-        xb = pl.load(src_ref, (pl.ds(r2, 1), pl.ds(q2, 1)))
-        return jax.lax.dynamic_update_slice(
-            xik, jnp.concatenate([xa, xb], axis=0), (i32(0), c)
-        )
-
-    xik0 = jax.lax.fori_loop(
-        0, block_c, stage, jnp.zeros((2, block_c), dt)
-    )
-
-    # ---- sweep: sequential in t, vectorized over the lane block.
-    rowb = rowS[...][:, :T].T  # (T, Cb)
-    colb = colS[...][:, :T].T
-    yv = y_ref[0]              # (3, T, Cb); preloaded so the aliased
-    grow = grow_ref[0]         # output writes below can never shadow reads
-    gcol = gcol_ref[0]
-    gsel = gsel_ref[0]
-    dinv = dinv_ref[0]
-    actv = act_ref[0] != 0
-    segv = seg_ref[0] != 0
+    """Sequential-in-t sweep of one lane block — ``ref.fused_diag_sweep``
+    restated on refs, op for op (the shared ``fused_step``)."""
 
     def body(t, carry):
-        t = i32(t)
-        xa, xb = carry  # (1, Cb) — the two folded x_ik carries
-        row = lambda a: jax.lax.dynamic_slice(a, (t, i32(0)), (1, block_c))
-        yrow = lambda m: jax.lax.dynamic_slice(
-            yv, (i32(m), t, i32(0)), (1, 1, block_c)
-        ).reshape(1, block_c)
-        xij, xjk = row(rowb), row(colb)
-        act, sg = row(actv), row(segv)
+        xa, xb = carry  # (1, Cb)
+        r = pl.ds(t, 1)
+        xij, xjk = row_ref[r, :], col_ref[r, :]
+        sg = seg_ref[r, :] != 0
         xc = jnp.where(sg, xb, xa)
         nij, nik, njk, t0, t1, t2 = fused_step(
-            xij, xc, xjk, yrow(0), yrow(1), yrow(2),
-            row(grow), row(gsel), row(gcol), row(dinv),
+            xij, xc, xjk, y_ref[0, r, :], y_ref[1, r, :], y_ref[2, r, :],
+            grow_ref[r, :], gsel_ref[r, :], gcol_ref[r, :], dinv_ref[r, :],
         )
-        for m, th in ((0, t0), (1, t1), (2, t2)):
-            pl.store(
-                oy_ref,
-                (pl.ds(i32(0), 1), pl.ds(i32(m), 1), pl.ds(t, 1),
-                 pl.ds(i32(0), block_c)),
-                th.reshape(1, 1, 1, block_c),
-            )
-        pl.store(dR, (pl.ds(t, 1), pl.ds(i32(0), block_c)),
-                 jnp.where(act, nij - xij, 0.0))
-        pl.store(dC, (pl.ds(t, 1), pl.ds(i32(0), block_c)),
-                 jnp.where(act, njk - xjk, 0.0))
-        nik = jnp.where(act, nik, xc)
+        orow_ref[r, :] = nij
+        ocol_ref[r, :] = njk
+        oy_ref[0, r, :] = t0
+        oy_ref[1, r, :] = t1
+        oy_ref[2, r, :] = t2
+        nik = jnp.where(act_ref[r, :] != 0, nik, xc)
         return jnp.where(sg, xa, nik), jnp.where(sg, nik, xb)
 
-    xa, xb = jax.lax.fori_loop(0, T, body, (xik0[0:1, :], xik0[1:2, :]))
+    # Mosaic unrolls a loop fully or not at all, so the partial unroll is
+    # spelled out: ``unroll`` steps per trip, then the remainder.
+    def body_u(i, carry):
+        for k in range(unroll):
+            carry = body(i * unroll + k, carry)
+        return carry
 
-    # ---- scatter: act-masked deltas, unfolded by the same dynamic offsets.
-    # Reuse the staging scratch in folded lane-major layout; the upper T
-    # columns are zero so segment-B windows read zeros beyond their extent.
-    zer = jnp.zeros((block_c, T), dt)
-    rowS[...] = jnp.concatenate([dR[...].T, zer], axis=1)
-    colS[...] = jnp.concatenate([dC[...].T, zer], axis=1)
-    tvec = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-
-    def scatter(c, _):
-        c = i32(c)
-        s1, s2, r1, q1, r2, q2 = lane_scalars(c)
-
-        def add(rows, cols, delta):
-            cur = pl.load(ox_ref, (rows, cols))
-            pl.store(ox_ref, (rows, cols), cur + delta)
-
-        dA = pl.load(rowS, (pl.ds(c, 1), pl.ds(i32(0), T)))
-        add(pl.ds(r1, 1), pl.ds(r1 + 1, T), jnp.where(tvec < s1, dA, 0.0))
-        dB = pl.load(rowS, (pl.ds(c, 1), pl.ds(s1, T)))
-        add(pl.ds(r2, 1), pl.ds(r2 + 1, T), dB)
-        cA = pl.load(colS, (pl.ds(c, 1), pl.ds(i32(0), T)))
-        cA = jnp.where(tvec < s1, cA, 0.0).reshape(T, 1)
-        add(pl.ds(r1 + 1, T), pl.ds(q1, 1), cA)
-        cB = pl.load(colS, (pl.ds(c, 1), pl.ds(s1, T))).reshape(T, 1)
-        add(pl.ds(r2 + 1, T), pl.ds(q2, 1), cB)
-        lane = lambda a, s: jax.lax.dynamic_slice(a, (i32(s), c), (1, 1))
-        da = lane(xa, 0) - lane(xik0, 0)
-        add(pl.ds(r1, 1), pl.ds(q1, 1), jnp.where(s1 > 0, da, 0.0))
-        db = lane(xb, 0) - lane(xik0, 1)
-        add(pl.ds(r2, 1), pl.ds(q2, 1), jnp.where(s2 > 0, db, 0.0))
-        return 0
-
-    jax.lax.fori_loop(0, block_c, scatter, 0)
+    carry = (xik_ref[0:1, :], xik_ref[1:2, :])
+    carry = jax.lax.fori_loop(0, T // unroll, body_u, carry)
+    xa, xb = jax.lax.fori_loop(T // unroll * unroll, T, body, carry)
+    oxik_ref[0:1, :] = xa
+    oxik_ref[1:2, :] = xb
 
 
-def _diag_one(xb, outb, lane, geo, seg_d, yb, gr, gc, gs, dv, ab, unroll):
-    """One diagonal of one instance — the vector engine's unit of work.
+def _sweep_tiles(rowb, colb, xikp, y, g_row, g_col, g_sel, dinv, act, seg,
+                 *, block_c: int, interpret: bool, unroll: int,
+                 alias: bool):
+    """The tpu engine's sweep of one diagonal for B instances.
 
-    Mirror of ``ref.fused_bucket_pass_ref``'s per-diagonal body: same
-    gathers, same staged sweep, same act-masked scatter. ``xb`` is the
-    gather source, ``outb`` the scatter target (the same values in
-    in-place mode; zeros in delta mode)."""
-    i1, k1, s1, i2, k2, s2 = lane
+    Shapes: (B, T, C) rowb/colb/gains/act, (B, 2, C) xikp, (B, 3, T, C)
+    y, (T, C) seg. Same results as ``fused_diag_sweep`` vmapped over B.
+    The lane axis is one block when C <= block_c; otherwise it is padded
+    to a multiple of ``block_c`` (a multiple of 128, the TPU lane tile)."""
+    B, T, C = rowb.shape
+    bc = C if C <= block_c else block_c
+    if bc != C and bc % 128:
+        raise ValueError(
+            f"block_c={block_c} must be a multiple of 128 to tile C={C} lanes"
+        )
+    Cp = -(-C // bc) * bc
+
+    def padc(a, fill=0):
+        if a.shape[-1] == Cp:
+            return a
+        pad = [(0, 0)] * (a.ndim - 1) + [(0, Cp - C)]
+        return jnp.pad(a, pad, constant_values=fill)
+
+    # Masks ship as int32: a dynamic single-row load of a packed (int8 or
+    # bool) tile is not a whole-tile access on the TPU.
+    mask = lambda m: padc(m.astype(jnp.int32))
+    tile = pl.BlockSpec((None, T, bc), lambda b, c: (b, 0, c))
+    pair = pl.BlockSpec((None, 2, bc), lambda b, c: (b, 0, c))
+    duals = pl.BlockSpec((None, 3, T, bc), lambda b, c: (b, 0, 0, c))
+    shared = pl.BlockSpec((T, bc), lambda b, c: (0, c))
+    dt = rowb.dtype
+    sds = lambda *s: jax.ShapeDtypeStruct(s, dt)
+    # 16 (T, bc) tiles double-buffered, plus headroom for Mosaic's own
+    # scratch; v5e has 128 MiB of VMEM.
+    vmem = 32 * T * bc * dt.itemsize + (8 << 20)
+    nrow, ncol, nxikp, ny = pl.pallas_call(
+        functools.partial(_sweep_kernel, T=T, unroll=unroll),
+        grid=(B, Cp // bc),
+        in_specs=[tile, tile, pair, duals] + [tile] * 5 + [shared],
+        out_specs=[tile, tile, pair, duals],
+        out_shape=[sds(B, T, Cp), sds(B, T, Cp), sds(B, 2, Cp),
+                   sds(B, 3, T, Cp)],
+        input_output_aliases={3: 3} if alias else {},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(min(vmem, 100 << 20))
+        ),
+        interpret=interpret,
+        name="metric_sweep",
+    )(
+        padc(rowb), padc(colb), padc(xikp), padc(y), padc(g_row, 1.0),
+        padc(g_col, 1.0), padc(g_sel, 1.0), padc(dinv, 1.0), mask(act),
+        mask(seg),
+    )
+    return nrow[..., :C], ncol[..., :C], nxikp[..., :C], ny[..., :C]
+
+
+def _vector_sweep(unroll: int):
+    """The vector engine's sweep: the jnp reference vmapped over B (seg
+    shared)."""
+    one = functools.partial(fused_diag_sweep, unroll=unroll)
+    return jax.vmap(one, in_axes=(0,) * 9 + (None,))
+
+
+def _gather_diag(xb, lane, geo):
+    """Folded row/column slices and the two carries of one diagonal of one
+    instance — the gathers of ``ref.fused_bucket_pass_ref``."""
+    i1, k1, _, i2, k2, _ = lane
     J, iN, kN = geo
     rowb = xb.at[iN, J].get(mode="fill", fill_value=0.0)
     colb = xb.at[J, kN].get(mode="fill", fill_value=0.0)
@@ -247,9 +197,14 @@ def _diag_one(xb, outb, lane, geo, seg_d, yb, gr, gc, gs, dv, ab, unroll):
         xb.at[i1, k1].get(mode="fill", fill_value=0.0),
         xb.at[i2, k2].get(mode="fill", fill_value=0.0),
     ])
-    nrow, ncol, nxikp, ny = fused_diag_sweep(
-        rowb, colb, xikp, yb, gr, gc, gs, dv, ab, seg_d, unroll=unroll
-    )
+    return rowb, colb, xikp
+
+
+def _scatter_diag(outb, lane, geo, ab, rowb, colb, xikp, nrow, ncol, nxikp):
+    """Act-masked deltas of one swept diagonal added into ``outb`` — the
+    scatters of ``ref.fused_bucket_pass_ref``."""
+    i1, k1, s1, i2, k2, s2 = lane
+    J, iN, kN = geo
     add = lambda a, idx, v: a.at[idx].add(
         v, mode="drop", unique_indices=True
     )
@@ -257,73 +212,65 @@ def _diag_one(xb, outb, lane, geo, seg_d, yb, gr, gc, gs, dv, ab, unroll):
     outb = add(outb, (J, kN), jnp.where(ab, ncol - colb, 0))
     outb = add(outb, (i1, k1), jnp.where(s1 > 0, nxikp[0] - xikp[0], 0))
     outb = add(outb, (i2, k2), jnp.where(s2 > 0, nxikp[1] - xikp[1], 0))
-    return outb, ny
+    return outb
 
 
-def _vector_diag_body(xv, out, lane, geo, segv, yv, grow, gcol, gsel,
-                      dinv, actv, unroll):
-    """One diagonal of the vector engine, vmapped over the batch."""
-    one = lambda xb, outb, yb, gr, gc, gs, dv, ab: _diag_one(
-        xb, outb, lane, geo, segv, yb, gr, gc, gs, dv, ab, unroll
+def _diag_step(xv, out, lane, geo, segv, yv, grow, gcol, gsel, dinv, actv,
+               sweep):
+    """One diagonal for B instances: gather (XLA), ``sweep``, scatter
+    (XLA). ``xv`` is the gather source, ``out`` the scatter target (the
+    same values in in-place mode; zeros in delta mode)."""
+    rowb, colb, xikp = jax.vmap(lambda xb: _gather_diag(xb, lane, geo))(xv)
+    nrow, ncol, nxikp, ny = sweep(
+        rowb, colb, xikp, yv, grow, gcol, gsel, dinv, actv, segv
     )
-    return jax.vmap(one)(xv, out, yv, grow, gcol, gsel, dinv, actv)
+    scatter = lambda ob, *a: _scatter_diag(ob, lane, geo, *a)
+    out = jax.vmap(scatter)(out, actv, rowb, colb, xikp, nrow, ncol, nxikp)
+    return out, ny
 
 
-def _vector_bucket_pass(x, yslab, lanes, g_row, g_col, g_sel, dinv, act,
-                        seg, geom, *, unroll, out_delta):
-    """XLA-native execution of the vector engine: per instance, one
-    ``lax.scan`` over the bucket's diagonals, vmapped over the batch —
-    the exact program structure the jnp fused reference compiles to, so
-    the batched kernel path costs what the vmapped reference costs.
-
-    This is the single-lane-block CPU dispatch of
-    ``fused_bucket_pass_pallas``: with one lane block the pallas grid
-    would be a single step whose interpret-mode wrapper contributes only
-    whole-buffer block copies around this same body, so the wrapper is
-    skipped. The pallas grid path remains the dma engine's contract (and
-    the multi-block vector fallback); results are bitwise identical."""
-    segs = seg != 0
-    acts = act != 0
+def _bucket_loop(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
+                 geom, *, sweep, out_delta):
+    """One loop over the bucket's diagonals, each a ``_diag_step`` on the
+    whole batch — the bucket program of both engines. The dual slab is
+    carried and updated in place, one diagonal at a time."""
     D = yslab.shape[1]
-    idx = jnp.arange(D, dtype=jnp.int32)
     at = lambda a, ax, d: jax.lax.dynamic_index_in_dim(
         a, d, ax, keepdims=False
     )
 
-    def one(xb, yb, gr, gc, gs, dv, ab):
-        def diag(carry, d):
-            xc, out = carry
-            out2, ny = _diag_one(
-                xc, out, at(lanes, 1, d), at(geom, 1, d), at(segs, 0, d),
-                at(yb, 0, d), at(gr, 0, d), at(gc, 0, d), at(gs, 0, d),
-                at(dv, 0, d), at(ab, 0, d), unroll,
-            )
-            # Delta mode gathers from the pristine X every diagonal
-            # (D == 1 by contract); in-place mode threads the iterate.
-            return (xc if out_delta else out2, out2), ny
+    def diag(d, carry):
+        xc, out, y = carry
+        out2, ny = _diag_step(
+            xc, out, at(lanes, 1, d), tuple(at(g, 0, d) for g in geom),
+            at(seg, 0, d) != 0, at(y, 1, d), at(g_row, 1, d),
+            at(g_col, 1, d), at(g_sel, 1, d), at(dinv, 1, d),
+            at(act, 1, d) != 0, sweep,
+        )
+        y = jax.lax.dynamic_update_index_in_dim(y, ny, d, 1)
+        # Delta mode gathers from the pristine X every diagonal (D == 1
+        # by contract); in-place mode threads the iterate.
+        return (xc if out_delta else out2, out2, y)
 
-        out0 = jnp.zeros_like(xb) if out_delta else xb
-        (_, nx), ny = jax.lax.scan(diag, (xb, out0), idx)
-        return nx, ny
-
-    return jax.vmap(one)(x, yslab, g_row, g_col, g_sel, dinv, acts)
+    out0 = jnp.zeros_like(x) if out_delta else x
+    _, nx, ny = jax.lax.fori_loop(0, D, diag, (x, out0, yslab))
+    return nx, ny
 
 
 def _fused_kernel_vector(
     lanes_ref,  # (6, D, Cp) int32 scalar-prefetch lane tables
-    x_ref,      # (B, np, np) whole padded batch (resident)
+    x_ref,      # (B, n, n) whole batch (resident)
     y_ref,      # (B, 1, 3, T, Cb)
     grow_ref,   # (B, 1, T, Cb) per-instance staged gains
     gcol_ref,
     gsel_ref,
     dinv_ref,
-    act_ref,    # (B, 1, T, Cb) int8 per-instance step mask
-    seg_ref,    # (1, T, Cb) int8 shared segment mask
+    act_ref,    # (B, 1, T, Cb) per-instance step mask
+    seg_ref,    # (1, T, Cb) shared segment mask
     geom_ref,   # (3, 1, T, Cb) int32 folded geometry: J, iN, kN
-    ox_ref,     # (B, np, np) working buffer: X, or the delta matrices
+    ox_ref,     # (B, n, n) working buffer: X, or the delta matrices
     oy_ref,     # (B, 1, 3, T, Cb)
     *,
-    T: int,
     block_c: int,
     unroll: int,
     out_delta: bool,
@@ -344,14 +291,67 @@ def _fused_kernel_vector(
     ).reshape(6, block_c)
     xv = x_ref[...] if out_delta else ox_ref[...]
     base = ox_ref[...] if out_delta else xv
-    nxv, ny = _vector_diag_body(
+    nxv, ny = _diag_step(
         xv, base, lane, geom_ref[...][:, 0], seg_ref[0] != 0,
         y_ref[...][:, 0], grow_ref[...][:, 0], gcol_ref[...][:, 0],
         gsel_ref[...][:, 0], dinv_ref[...][:, 0], act_ref[...][:, 0] != 0,
-        unroll,
+        _vector_sweep(unroll),
     )
     ox_ref[...] = nxv
     oy_ref[...] = ny[:, None]
+
+
+def _vector_tiled_pass(x, yslab, lanes, g_row, g_col, g_sel, dinv, act,
+                       seg, geom, *, block_c, unroll, out_delta):
+    """Multi-block vector engine: a pallas grid of (diagonal, lane block)
+    steps in interpret mode. The engine gathers/scatters by index with
+    fill/drop semantics, so neither the lane axis nor X needs padding
+    beyond whole lane blocks."""
+    B, n, _ = x.shape
+    _, D, _, T, C = yslab.shape
+    bc = block_c
+    Cp = -(-C // bc) * bc
+
+    def padc(a, fill):
+        if a.shape[-1] == Cp:
+            return a
+        pad = [(0, 0)] * (a.ndim - 1) + [(0, Cp - C)]
+        return jnp.pad(a, pad, constant_values=fill)
+
+    lanes_p = jnp.concatenate(
+        [padc(lanes[:2], -1), padc(lanes[2:3], 0),
+         padc(lanes[3:5], -1), padc(lanes[5:6], 0)], axis=0
+    )
+    x_spec = pl.BlockSpec((B, n, n), lambda b, d, c, s: (0, 0, 0))
+    y_spec = pl.BlockSpec(
+        (B, 1, 3, T, bc), lambda b, d, c, s: (0, d, 0, 0, c)
+    )
+    tc_spec = pl.BlockSpec((B, 1, T, bc), lambda b, d, c, s: (0, d, 0, c))
+    seg_spec = pl.BlockSpec((1, T, bc), lambda b, d, c, s: (d, 0, c))
+    geo_spec = pl.BlockSpec((3, 1, T, bc), lambda b, d, c, s: (0, d, 0, c))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(1, D, Cp // bc),
+        in_specs=[x_spec, y_spec] + [tc_spec] * 5 + [seg_spec, geo_spec],
+        out_specs=[x_spec, y_spec],
+    )
+    nx, ny = pl.pallas_call(
+        functools.partial(
+            _fused_kernel_vector, block_c=bc, unroll=unroll,
+            out_delta=out_delta,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, n, n), x.dtype),
+            jax.ShapeDtypeStruct((B, D, 3, T, Cp), x.dtype),
+        ],
+        interpret=True,
+    )(
+        lanes_p, x, padc(yslab, 0), padc(g_row, 1.0), padc(g_col, 1.0),
+        padc(g_sel, 1.0), padc(dinv, 1.0), padc(act, 0), padc(seg, 0),
+        padc(jnp.stack(geom), -1),
+    )
+    return nx, ny[..., :C]
 
 
 def fused_bucket_pass_pallas(
@@ -380,18 +380,20 @@ def fused_bucket_pass_pallas(
       x: (B, n, n) iterates.
       yslab: (B, D, 3, T, C) schedule-native dual slabs.
       lanes: (6, D, C) int32 — i1, k1, s1, i2, k2, s2 lane tables, shared
-        across the batch (scalar-prefetched into SMEM).
+        across the batch.
       g_row/g_col/g_sel/dinv: (B, D, T, C) per-instance staged gains —
         runtime operands, never trace constants.
       act: (B, D, T, C) per-instance (ghost-aware) step masks.
       seg: (D, T, C) shared segment mask.
-      geom: (3, D, T, C) int32 folded geometry (J, iN, kN) — consumed by
-        the vector engine; ignored (and not shipped) in dma mode.
-      mode: "dma" (TPU per-lane engine) or "vector" (CPU/interpret
-        vmapped engine). Same contract, same results.
-      unroll: inner-scan unroll of the vector engine's staged sweep.
-      in_place: alias X and the dual slab input→output (enable under jit
-        only, like the earlier generations).
+      geom: the folded geometry J, iN, kN — three (D, T, C) int32
+        arrays (a sequence, or one stacked (3, D, T, C) array).
+      block_c: lane block of the sweep (tpu mode: C itself, or a multiple
+        of 128 when C is wider).
+      mode: "tpu" (Pallas sweep kernel; the TPU engine) or "vector"
+        (interpret-only vmapped jnp sweep). Same contract, same results.
+      unroll: unroll of the sequential-in-t sweep loop.
+      in_place: alias the dual slab input→output inside the sweep kernel
+        (enable under jit only, like the earlier generations).
       out_delta: return the act-masked update deltas scattered into zeros
         instead of the updated X (requires D == 1 — the sharded solver's
         per-diagonal psum contract). X is read-only; duals still update.
@@ -399,138 +401,30 @@ def fused_bucket_pass_pallas(
     Returns (new_x, new_yslab) — (B, n, n) and (B, D, 3, T, C); new_x is
     the delta matrix batch when ``out_delta``.
     """
-    if mode not in ("dma", "vector"):
+    if mode not in ("tpu", "vector"):
         raise ValueError(f"unknown megakernel mode {mode!r}")
-    B, n, _ = x.shape
-    _, D, _, T, C = yslab.shape
+    if mode == "vector" and not interpret:
+        raise ValueError(
+            "the vector engine runs in interpret mode only; compiled "
+            "kernels use mode='tpu'"
+        )
+    D, C = yslab.shape[1], yslab.shape[-1]
     if out_delta and D != 1:
         raise ValueError("out_delta requires a single-diagonal call (D=1)")
-    if mode == "vector" and block_c >= C:
-        # Single lane block: dispatch the vector engine XLA-native (see
-        # _vector_bucket_pass) — the pallas wrapper would add only
-        # whole-buffer copies around the identical body.
-        return _vector_bucket_pass(
-            x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
-            geom.astype(jnp.int32), unroll=unroll, out_delta=out_delta,
+    geom = tuple(g.astype(jnp.int32) for g in geom)
+    operands = (x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg, geom)
+    if mode == "tpu":
+        sweep = functools.partial(
+            _sweep_tiles, block_c=block_c, interpret=interpret,
+            unroll=unroll, alias=in_place,
         )
-    dt = x.dtype
-    if mode == "vector":
-        # The vector engine gathers/scatters by index with fill/drop
-        # semantics (like the jnp ref), so neither the lane axis nor X
-        # needs padding — pad-free keeps the multi-block CPU path close
-        # to the ref's cost.
-        bc = block_c
+    elif block_c >= C:
+        # Single lane block: the vector engine runs as plain XLA — a
+        # pallas grid of one step would add only whole-buffer copies
+        # around the identical body.
+        sweep = _vector_sweep(unroll)
     else:
-        bc = min(block_c, max(8, -(-C // 8) * 8))
-    Cp = -(-C // bc) * bc
-
-    def padc(a, fill):
-        if a.shape[-1] == Cp:
-            return a
-        pad = [(0, 0)] * (a.ndim - 1) + [(0, Cp - C)]
-        return jnp.pad(a, pad, constant_values=fill)
-
-    # dma mode pads X so every fixed-length-T slice window stays in
-    # bounds (the pad region only ever receives exact zeros); the vector
-    # engine runs on the unpadded iterate.
-    np_ = n if mode == "vector" else n + T + 1
-    xp = x if np_ == n else jnp.pad(x, ((0, 0), (0, np_ - n), (0, np_ - n)))
-    lanes_p = jnp.concatenate(
-        [padc(lanes[:2], -1), padc(lanes[2:3], 0),
-         padc(lanes[3:5], -1), padc(lanes[5:6], 0)], axis=0
-    )
-    y_p = padc(yslab, 0)
-    g_row_p, g_col_p = padc(g_row, 1.0), padc(g_col, 1.0)
-    g_sel_p, dinv_p = padc(g_sel, 1.0), padc(dinv, 1.0)
-    # int8 masks are a TPU operand-dtype requirement; the vector engine
-    # ships the bools straight through (the cast is a slab-sized pass
-    # per call that the CPU path doesn't need).
-    mask_dt = jnp.int8 if mode == "dma" else act.dtype
-    act_p = padc(act.astype(mask_dt), 0)
-    seg_p = padc(seg.astype(mask_dt), 0)
-
-    grid = (B if mode == "dma" else 1, D, Cp // bc)
-    if mode == "dma":
-        # Batch axis squeezed out of every per-instance BlockSpec: the
-        # kernel body sees gen-2 shapes, one instance at a time.
-        x_spec = pl.BlockSpec((None, np_, np_), lambda b, d, c, s: (b, 0, 0))
-        y_spec = pl.BlockSpec(
-            (None, 1, 3, T, bc), lambda b, d, c, s: (b, d, 0, 0, c)
+        return _vector_tiled_pass(
+            *operands, block_c=block_c, unroll=unroll, out_delta=out_delta
         )
-        tc_spec = pl.BlockSpec(
-            (None, 1, T, bc), lambda b, d, c, s: (b, d, 0, c)
-        )
-        seg_spec = pl.BlockSpec((1, T, bc), lambda b, d, c, s: (d, 0, c))
-        in_specs = [x_spec, y_spec] + [tc_spec] * 5 + [seg_spec]
-        operands = (xp, y_p, g_row_p, g_col_p, g_sel_p, dinv_p, act_p, seg_p)
-        out_specs = [x_spec, y_spec]
-        out_shape = [
-            jax.ShapeDtypeStruct((B, np_, np_), dt),
-            jax.ShapeDtypeStruct((B, D, 3, T, Cp), dt),
-        ]
-        scratch = [
-            pltpu.VMEM((bc, 2 * T), dt),
-            pltpu.VMEM((bc, 2 * T), dt),
-            pltpu.VMEM((T, bc), dt),
-            pltpu.VMEM((T, bc), dt),
-        ]
-        kernel = functools.partial(
-            _fused_kernel_dma, T=T, block_c=bc, out_delta=out_delta
-        )
-    else:
-        geom_p = padc(geom.astype(jnp.int32), -1)
-        x_spec = pl.BlockSpec(
-            (B, np_, np_), lambda b, d, c, s: (0, 0, 0)
-        )
-        y_spec = pl.BlockSpec(
-            (B, 1, 3, T, bc), lambda b, d, c, s: (0, d, 0, 0, c)
-        )
-        tc_spec = pl.BlockSpec(
-            (B, 1, T, bc), lambda b, d, c, s: (0, d, 0, c)
-        )
-        seg_spec = pl.BlockSpec((1, T, bc), lambda b, d, c, s: (d, 0, c))
-        geo_spec = pl.BlockSpec(
-            (3, 1, T, bc), lambda b, d, c, s: (0, d, 0, c)
-        )
-        vkernel = _fused_kernel_vector
-        in_specs = (
-            [x_spec, y_spec] + [tc_spec] * 5 + [seg_spec, geo_spec]
-        )
-        operands = (
-            xp, y_p, g_row_p, g_col_p, g_sel_p, dinv_p, act_p, seg_p, geom_p
-        )
-        out_specs = [x_spec, y_spec]
-        out_shape = [
-            jax.ShapeDtypeStruct((B, np_, np_), dt),
-            jax.ShapeDtypeStruct((B, D, 3, T, Cp), dt),
-        ]
-        scratch = []
-        kernel = functools.partial(
-            vkernel, T=T, block_c=bc, unroll=unroll,
-            out_delta=out_delta,
-        )
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=scratch,
-    )
-    # Operand indices include the scalar-prefetch arg (index 0): X is
-    # operand 1, the dual slab operand 2. Delta mode must keep X intact
-    # (it is re-read by the caller's psum merge), so only duals alias.
-    if not in_place:
-        aliases = {}
-    elif out_delta:
-        aliases = {2: 1}
-    else:
-        aliases = {1: 0, 2: 1}
-    nx, ny = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(lanes_p, *operands)
-    return nx[:, :n, :n], ny[..., :C]
+    return _bucket_loop(*operands, sweep=sweep, out_delta=out_delta)

@@ -67,25 +67,25 @@ def _sweep_kernel(
     def body(t, carry):
         xa, xb = carry
         sl = (pl.ds(t, 1), slice(None))
-        xij = pl.load(rowb_ref, sl)
-        xjk = pl.load(colb_ref, sl)
-        v0 = pl.load(y0_ref, sl)
-        v1 = pl.load(y1_ref, sl)
-        v2 = pl.load(y2_ref, sl)
-        act = pl.load(act_ref, sl) != 0
-        sg = pl.load(seg_ref, sl) != 0
-        iwij = 1.0 / pl.load(wrow_ref, sl)
-        iwjk = 1.0 / pl.load(wcol_ref, sl)
+        xij = rowb_ref[sl]
+        xjk = colb_ref[sl]
+        v0 = y0_ref[sl]
+        v1 = y1_ref[sl]
+        v2 = y2_ref[sl]
+        act = act_ref[sl] != 0
+        sg = seg_ref[sl] != 0
+        iwij = 1.0 / wrow_ref[sl]
+        iwjk = 1.0 / wcol_ref[sl]
         xc = jnp.where(sg, xb, xa)
         iw_ik = jnp.where(sg, iw_b, iw_a)
         nij, nik, njk, t0, t1, t2 = triplet_visit(
             xij, xc, xjk, v0, v1, v2, iwij, iw_ik, iwjk, eps
         )
-        pl.store(orow_ref, sl, jnp.where(act, nij, xij))
-        pl.store(ocol_ref, sl, jnp.where(act, njk, xjk))
-        pl.store(o0_ref, sl, jnp.where(act, t0, v0))
-        pl.store(o1_ref, sl, jnp.where(act, t1, v1))
-        pl.store(o2_ref, sl, jnp.where(act, t2, v2))
+        orow_ref[sl] = jnp.where(act, nij, xij)
+        ocol_ref[sl] = jnp.where(act, njk, xjk)
+        o0_ref[sl] = jnp.where(act, t0, v0)
+        o1_ref[sl] = jnp.where(act, t1, v1)
+        o2_ref[sl] = jnp.where(act, t2, v2)
         nik = jnp.where(act, nik, xc)
         return jnp.where(sg, xa, nik), jnp.where(sg, nik, xb)
 

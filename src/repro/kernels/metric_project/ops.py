@@ -1,8 +1,8 @@
 """Jitted public wrappers for the metric-projection sweep kernels.
 
-On TPU, ``interpret=False`` compiles the Mosaic kernel; on CPU (this
-container) kernels execute in interpret mode, which is how they are
-validated against the jnp references in tests.
+On TPU, ``interpret=False`` compiles the Mosaic kernels; on CPU kernels
+execute in interpret mode, which is how they are validated against the
+jnp references in tests.
 
 Production entry points — all three route the gen-3 megakernel
 (``fused_pass.py``, DESIGN.md §10), one compiled program per bucket
@@ -11,7 +11,7 @@ shape with per-instance data as runtime operands:
   * ``fused_bucket_pass``         — solo path (``ParallelSolver``): one
     instance lifted to a unit batch axis.
   * ``fused_bucket_pass_batched`` — serve batch path (``BatchedSolver``):
-    a whole (B, ...) bucket in ONE ``pallas_call``; new instances or
+    a whole (B, ...) bucket in one bucket program; new instances or
     batches never recompile (gains/masks are operands).
   * ``fused_diag_pass_delta``     — sharded path (``ShardedSolver``): one
     diagonal per call in delta-output mode — the kernel returns the
@@ -69,9 +69,9 @@ def _on_tpu() -> bool:
 
 
 def _kernel_mode() -> str:
-    """Gen-3 staging engine: the per-lane DMA body on real TPUs, the
-    vmapped vector body under interpret execution (DESIGN.md §10)."""
-    return "dma" if _on_tpu() else "vector"
+    """Gen-3 staging engine: the compiled Pallas sweep on TPUs, the
+    vmapped jnp sweep under interpret execution (DESIGN.md §10)."""
+    return "tpu" if _on_tpu() else "vector"
 
 
 # eps is static: sweep_pallas bakes it into the kernel body as a python
@@ -129,11 +129,12 @@ def diagonal_sweep_slab(rowb, colb, xikp, yslab, w_row, w_col, w_ikp, active,
 )
 def _fused_pass_jit(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
                     geom, block_c, interpret, mode, unroll, out_delta):
-    # in_place is safe here for both X and the dual slab: under jit, XLA
-    # copies any donated buffer that is still live in the caller. All
-    # per-instance data are operands, so every solo/batched/sharded call
-    # of one bucket shape hits this one cache entry — zero recompiles
-    # across instances (the §10 contract, pinned by tests).
+    # in_place (the sweep kernel writes its dual block over its input) is
+    # safe here: under jit, XLA copies any donated buffer that is still
+    # live in the caller. All per-instance data are operands, so every
+    # solo/batched/sharded call of one bucket shape hits this one cache
+    # entry — zero recompiles across instances (the §10 contract, pinned
+    # by tests).
     # inline=True: when a runner jits a whole pass/chunk around this call
     # (BatchedSolver chunks, ShardedSolver passes), the bucket program is
     # inlined into the enclosing jaxpr instead of staying an opaque pjit
@@ -181,7 +182,7 @@ def fused_bucket_pass(x, yslab, bucket, block_c: int | None = None,
     lanes = jnp.stack(
         [bucket[key] for key in ("i", "k", "s", "i2", "k2", "s2")]
     )
-    geom = jnp.stack([bucket["J"], bucket["iN"], bucket["kN"]])
+    geom = (bucket["J"], bucket["iN"], bucket["kN"])
     one = lambda a: a[None]
     nx, ny = _fused_pass_jit(
         x[None], yslab[None], lanes,
@@ -195,8 +196,8 @@ def fused_bucket_pass(x, yslab, bucket, block_c: int | None = None,
 
 def fused_bucket_pass_batched(x, yslab, geo, gains,
                               block_c: int | None = None, unroll: int = 4):
-    """Whole-bucket fused pass of a B-instance serve batch in ONE
-    ``pallas_call`` (DESIGN.md §10). ``geo`` holds the bucket's shared
+    """Whole-bucket fused pass of a B-instance serve batch in one bucket
+    program (DESIGN.md §10). ``geo`` holds the bucket's shared
     statics (lane tables ``i/k/s/i2/k2/s2``, geometry ``J/iN/kN``, the
     ``seg`` mask — pure functions of the bucket shape); ``gains`` the
     per-instance operands stacked with a leading B axis
@@ -211,7 +212,7 @@ def fused_bucket_pass_batched(x, yslab, geo, gains,
     """
     bc = block_c or _DEFAULT_BLOCK_C
     lanes = jnp.stack([geo[key] for key in ("i", "k", "s", "i2", "k2", "s2")])
-    geom = jnp.stack([geo["J"], geo["iN"], geo["kN"]])
+    geom = (geo["J"], geo["iN"], geo["kN"])
     return _fused_pass_jit(
         x, yslab, lanes, gains["g_row"], gains["g_col"], gains["g_sel"],
         gains["dinv"], gains["act"], geo["seg"], geom,

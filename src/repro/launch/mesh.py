@@ -27,6 +27,8 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
+from repro.launch.compile_cache import use_compile_cache
+
 __all__ = [
     "device_memory_bytes",
     "initialize_distributed",
@@ -171,7 +173,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tol", type=float, default=1e-3)
     ap.add_argument("--use-kernel", action="store_true")
     args = ap.parse_args(argv)
-
+    use_compile_cache()
     dist = initialize_distributed(
         coordinator_address=args.coordinator,
         num_processes=args.num_processes,

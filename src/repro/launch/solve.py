@@ -39,6 +39,7 @@ from repro.core.parallel_dykstra import ParallelSolver
 from repro.core.sharded_dykstra import ShardedSolver
 from repro.graphs import generators, io as gio, jaccard
 from repro.launch import elastic, mesh as mesh_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import checkpoint as ckpt_lib
 
 
@@ -180,6 +181,7 @@ def main(argv=None):
                     help="additionally draw a seeded random FaultPlan "
                          "(replayable chaos)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.block_c is not None:
         from repro.kernels.metric_project import ops as kops

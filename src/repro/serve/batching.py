@@ -154,8 +154,8 @@ class BatchedSolver:
         ``ParallelSolver.bucket_diagonals``).
       sweep_unroll: inner-scan unroll of the fused sweep.
       use_kernel: route the triangle sweeps through the gen-3 Pallas
-        megakernel — the whole (B, ...) bucket runs as ONE ``pallas_call``
-        per bucket per pass (DESIGN.md §10), bitwise-equal per instance
+        megakernel — the whole (B, ...) bucket runs as one bucket program
+        per pass (DESIGN.md §10), bitwise-equal per instance
         to the vmapped jnp fused reference. Gains/masks stay runtime
         operands either way, so new batches never recompile.
     """

@@ -34,6 +34,7 @@ import numpy as np
 
 from repro.core import metrics_device, problems, rounding
 from repro.graphs import generators, jaccard
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import buckets as bk
 from repro.serve.scheduler import BatchScheduler
 
@@ -219,6 +220,7 @@ def main(argv=None):
                     help="Poisson arrival rate (instances/sec); default: "
                          "submit everything as one burst")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     sizes = [int(s) for s in args.sizes.split(",")]
     ladder = tuple(int(s) for s in args.ladder.split(","))

@@ -13,9 +13,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # module still runs. Test modules use:
 #
 #     try:
-#         from hypothesis import given, settings, strategies as st
+#         from hypothesis import example, given, settings, strategies as st
 #     except ImportError:
-#         from conftest import given, settings, st
+#         from conftest import example, given, settings, st
 # ---------------------------------------------------------------------------
 
 
@@ -32,6 +32,9 @@ st = _SkipStrategies()
 
 def settings(*_a, **_k):
     return lambda f: f
+
+
+example = settings
 
 
 def given(*_a, **_k):

@@ -340,3 +340,29 @@ def test_device_metrics_keys_match_host_report():
     dev_d = solver.device_metrics(st_, include_duals=True)
     assert set(host_d) == set(dev_d)
     assert {"dual_min", "dual_max", "dual_l1", "active_constraints"} <= set(dev_d)
+
+
+def test_run_until_staged_operand_mode(x64):
+    """The staged arrays are a jit operand of the runners, not embedded
+    constants: the runner still stops at exactly max_passes through a
+    partial chunk, on the same iterate as plain passes."""
+    solver = ParallelSolver(_problem(10, seed=2), dtype=np.float64)
+    traced = []
+    one_pass = solver._one_pass
+
+    def spy(st):
+        leaf = jax.tree.leaves(solver._staged_view())[0]
+        traced.append(isinstance(leaf, jax.core.Tracer))
+        return one_pass(st)
+
+    solver._one_pass = spy
+    st_, info = solver.run_until(tol=0.0, max_passes=7, check_every=3)
+    assert traced and all(traced)
+    solver._one_pass = one_pass
+    assert info["passes"] == 7 and not info["converged"]
+    np.testing.assert_array_equal(
+        np.asarray(st_.x), np.asarray(solver.run(passes=7).x)
+    )
+    st2, info2 = solver.run_until(st_, tol=0.0, max_passes=7, check_every=3)
+    assert info2["passes"] == 7
+    np.testing.assert_array_equal(np.asarray(st2.x), np.asarray(st_.x))

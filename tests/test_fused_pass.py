@@ -196,13 +196,12 @@ def test_megakernel_compile_counter():
 
 
 def test_demoted_gen1_fallback_warns():
-    """use_kernel=True with fused=False has no kernel path anymore (gen-1
-    is test-oracle-only): the fallback to the jnp sweep must be LOUD."""
+    """use_kernel=True with fused=False has no kernel path (gen-1 is
+    test-oracle-only): asking for it is an error, never a silent jnp
+    sweep."""
     p = _l2_problem(10, seed=2)
-    solver = ParallelSolver(p, use_kernel=True, fused=False,
-                            bucket_diagonals=2)
-    with pytest.warns(UserWarning, match="test-oracle"):
-        solver.run(passes=1)
+    with pytest.raises(ValueError, match="test-oracle"):
+        ParallelSolver(p, use_kernel=True, fused=False, bucket_diagonals=2)
 
 
 def test_gen1_oracle_vs_gen3_parity(x64):
@@ -401,13 +400,13 @@ def _engine_bucket_pass(engine, x, yb, stage, am):
         one(stage["g_col"]), one(stage["g_sel"]), one(stage["dinv"]),
         one(am), stage["seg"], geom,
         block_c=2 if engine == "vector-tiled" else 128,
-        interpret=True, mode="dma" if engine == "dma" else "vector",
+        interpret=True, mode="tpu" if engine == "tpu" else "vector",
     )
     return nx[0], ny[0]
 
 
 @pytest.mark.parametrize(
-    "engine", ["vector", "vector-tiled", "dma"]
+    "engine", ["vector", "vector-tiled", "tpu"]
 )
 def test_property_masked_cells_are_fixed_points(engine):
     """Ghost cells AND dynamically forgotten cells are structural fixed

@@ -8,9 +8,9 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 except ImportError:  # tier-1 containers lack hypothesis; @given tests skip
-    from conftest import given, settings, st
+    from conftest import example, given, settings, st
 
 from repro.core import convergence, dykstra, problems
 from repro.core.parallel_dykstra import ParallelSolver
@@ -45,18 +45,35 @@ def test_metric_input_is_fixed_point(n, seed):
 
 
 @given(n=st.integers(4, 10), seed=st.integers(0, 10**6))
+@example(n=7, seed=0)
 @settings(max_examples=10, deadline=None)
 def test_duals_nonnegative_and_violation_decreases(n, seed):
+    """θ ≥ 0, and the dual objective never falls. Dykstra is exact
+    coordinate ascent on the dual, whose value here (b = 0) is
+    −(ε/2)·xᵀWx (core/convergence.py), so xᵀW x must not rise from pass
+    to pass. The max violation itself is not monotone: at n=7, seed=0 it
+    is 3.0e-8 after 2 passes and 2.5e-2 after 5, as the dual corrections
+    push x back out of the feasible set; it falls only in the limit."""
     rng = np.random.default_rng(seed)
     d = np.triu((rng.uniform(0, 1, (n, n)) > 0.5).astype(float), k=1)
     p = problems.metric_nearness_l2(d)
+    iu = np.triu_indices(n, 1)
+    # f32 iterates: x'Wx is exact up to rounding of x (measured rises stay
+    # under 1e-7 of d'Wd).
+    floor = 1e-6 * max(float(np.sum(p.w[iu] * d[iu] ** 2)), 1.0)
+
+    def xwx(s):
+        x = np.asarray(s.x, np.float64)
+        return float(np.sum(p.w[iu] * x[iu] ** 2))
+
     solver = ParallelSolver(p)
-    st1 = solver.run(passes=2)
-    st2 = solver.run(st1, passes=20)
-    assert min(float(np.asarray(y).min()) for y in st2.yd) >= -1e-6  # θ ≥ 0
-    v1 = convergence.max_violation(p, np.asarray(st1.x, np.float64))
-    v2 = convergence.max_violation(p, np.asarray(st2.x, np.float64))
-    assert v2 <= v1 + 1e-6
+    st_ = solver.run(passes=2)
+    q = [xwx(st_)]
+    for _ in range(20):
+        st_ = solver.run(st_, passes=1)
+        q.append(xwx(st_))
+    assert min(float(np.asarray(y).min()) for y in st_.yd) >= -1e-6  # θ ≥ 0
+    assert max(np.diff(q)) <= floor
 
 
 @given(seed=st.integers(0, 10**6))

@@ -310,6 +310,7 @@ def test_mesh_entry_8_devices_subprocess():
     global mesh line + a converged sharded solve certificate."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     env.pop("XLA_FLAGS", None)
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.mesh",

@@ -170,3 +170,13 @@ def test_layout_memory_is_3_choose_n3_plus_padding():
     assert slab_floats >= real  # covers every dual
     assert slab_floats <= 1.7 * real  # bounded padding
     assert slab_floats < n ** 3  # strictly under the dense tensor
+
+
+@pytest.mark.parametrize("n,nb,procs", [(3, 1, 1), (17, 3, 2), (40, 6, 1),
+                                        (41, 4, 4)])
+def test_slab_dims_match_layout(n, nb, procs):
+    """The cheap shape planner agrees with the full layout build."""
+    lay = sched.build_layout(n, num_buckets=nb, procs=procs)
+    want = [(b.slab_shape[1], b.slab_shape[3], b.slab_shape[4])
+            for b in lay.buckets]
+    assert sched.slab_dims(n, num_buckets=nb, procs=procs) == want
