@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import metrics_device, schedule as sched
 
 __all__ = [
@@ -276,7 +277,8 @@ class SolverRuntime:
         return self._staged_arrays() if view is None else view
 
     def _jit_staged(self, fn):
-        """``jax.jit(fn)``, with the staged arrays passed as an operand."""
+        """``jax.jit(fn)``, with the staged arrays passed as an operand.
+        The returned call's ``lower(*args)`` lowers the same program."""
 
         def traced(staged, *args):
             outer, self._staged_tracer = self._staged_tracer, staged
@@ -286,7 +288,12 @@ class SolverRuntime:
                 self._staged_tracer = outer
 
         jitted = jax.jit(traced)
-        return lambda *args: jitted(self._staged_arrays(), *args)
+
+        def call(*args):
+            return jitted(self._staged_arrays(), *args)
+
+        call.lower = lambda *args: jitted.lower(self._staged_arrays(), *args)
+        return call
 
     @functools.cached_property
     def _engine_cache(self) -> dict:
@@ -513,8 +520,10 @@ class SolverRuntime:
                 def body(carry):
                     s, viol_p, gap_p, obj_prev, _, resbuf, k, div = carry
                     s2 = chunk(s)
-                    viol, gap = self._stopping_pair(s2)
-                    obj = self._wide_objective(s2)
+                    with jax.named_scope("repro.chunk.probe"):
+                        viol, gap = self._stopping_pair(s2)
+                    with jax.named_scope("repro.chunk.objective"):
+                        obj = self._wide_objective(s2)
                     res = jnp.max(jnp.abs(s2.x - s.x)).astype(dt)
                     # Divergence guard: isfinite of the residual probe is
                     # folded into the stopping decision — a NaN/Inf chunk
@@ -651,34 +660,61 @@ class SolverRuntime:
         max_passes = int(max_passes)
         tol = float(tol)
 
+        syncs = 0
+
+        def get(tree):
+            # one host round trip: a read of device values
+            nonlocal syncs
+            syncs += 1
+            return jax.device_get(tree)
+
+        def launch(program, *args):
+            # one host round trip: a program dispatched from the host (the
+            # stop decision's few one-op programs and their read count as
+            # one)
+            nonlocal syncs
+            syncs += 1
+            return program(*args)
+
         def host(pair):
-            v, g = jax.device_get(pair)
+            v, g = get(pair)
             return float(v), float(g)
 
-        fn = self._until_fn(check_every, stop_rule, residual_history)
-        st, viol, gap, obj, prev_obj, resbuf, k, div = fn(st, tol, max_passes)
-        viol, gap = host((viol, gap))
-        obj, prev_obj = host((obj, prev_obj))
-        k = int(k)
-        diverged = bool(jax.device_get(div))
-        resbuf = np.asarray(jax.device_get(resbuf), np.float64)
-        residuals = (
-            resbuf[:k] if k <= residual_history
-            else np.roll(resbuf, -(k % residual_history))
-        )
-        self.last_residuals = residuals
-        qp, lp = (float(v) for v in jax.device_get(self._objectives_fn()(st)))
-        if not np.isfinite(viol):
-            # no chunk ran (state already at/over max_passes), or the
-            # guard tripped on the very first chunk: probe the returned
-            # state once so the caller still gets a real stopping pair.
-            viol, gap = host(self._probe_fn()(st))
-            obj = qp
-        converged = not diverged and bool(
-            stop_converged(stop_rule, tol, viol, gap, obj, prev_obj)
-        )
+        with obs.span("repro.run_until"):
+            with obs.span("repro.run_until.launch"):
+                fn = self._until_fn(check_every, stop_rule, residual_history)
+                st, viol, gap, obj, prev_obj, resbuf, k, div = launch(
+                    fn, st, tol, max_passes
+                )
+            with obs.span("repro.run_until.wait"):
+                viol, gap = host((viol, gap))
+            with obs.span("repro.run_until.harvest"):
+                obj, prev_obj = host((obj, prev_obj))
+                k = int(get(k))
+                diverged = bool(get(div))
+                resbuf = np.asarray(get(resbuf), np.float64)
+                residuals = (
+                    resbuf[:k] if k <= residual_history
+                    else np.roll(resbuf, -(k % residual_history))
+                )
+                self.last_residuals = residuals
+                qp, lp = (float(v) for v in
+                          get(launch(self._objectives_fn(), st)))
+                if not np.isfinite(viol):
+                    # no chunk ran (state already at/over max_passes), or
+                    # the guard tripped on the very first chunk: probe the
+                    # returned state once so the caller still gets a real
+                    # stopping pair.
+                    viol, gap = host(launch(self._probe_fn(), st))
+                    obj = qp
+                converged = not diverged and bool(launch(
+                    stop_converged, stop_rule, tol, viol, gap, obj, prev_obj
+                ))
+                passes = int(get(st.passes))
+        obs.count("repro.run_until.calls")
+        obs.count("repro.run_until.syncs", syncs)
         info = {
-            "passes": int(st.passes),
+            "passes": passes,
             "converged": converged,
             "diverged": diverged,
             "max_violation": viol,

@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import metrics_device, schedule as sched
 from repro.core.engine import SolverRuntime
 from repro.core.problems import MetricQP
@@ -165,12 +166,13 @@ class ParallelSolver(SolverRuntime):
                 "per-diagonal kernel is test-oracle only, so the legacy "
                 "path has no kernel sweep."
             )
-        self.layout = sched.build_layout(
-            self.n,
-            num_buckets=self.bucket_diagonals,
-            procs=1,
-            pad_sets_to=pad_sets_to,
-        )
+        with obs.span("repro.stage.layout"):
+            self.layout = sched.build_layout(
+                self.n,
+                num_buckets=self.bucket_diagonals,
+                procs=1,
+                pad_sets_to=pad_sets_to,
+            )
         self._w = jnp.asarray(problem.w, dtype)
         self._d = jnp.asarray(problem.d, dtype)
         self._wf = (
@@ -188,54 +190,66 @@ class ParallelSolver(SolverRuntime):
         the carry gathers; the staged geometry/mask/gain slabs
         (DESIGN.md §4) — everything the fused pass needs beyond X and the
         duals — are built only when fused execution is on (the legacy
-        path re-derives them at runtime and must not pay their memory)."""
-        buckets = [
-            dict(
-                i=jnp.asarray(bl.i[0], jnp.int32),
-                k=jnp.asarray(bl.k[0], jnp.int32),
-                s=jnp.asarray(bl.sizes[0], jnp.int32),
-                i2=jnp.asarray(bl.i2[0], jnp.int32),
-                k2=jnp.asarray(bl.k2[0], jnp.int32),
-                s2=jnp.asarray(bl.sizes2[0], jnp.int32),
-                T=bl.T,
-            )
-            for bl in self.layout.buckets
-        ]
-        if not self.fused:
-            return buckets
-        npdt = np.dtype(self.dtype)
-        one = npdt.type(1.0)
-        epsc = npdt.type(self.p.eps)
-        stage = sched.build_static_stage(self.layout, self.p.w, npdt)
-        for b, sb in zip(buckets, stage):
-            # Ghost padding (DESIGN.md §8): a triplet is real iff its
-            # largest index kN < n_real, so the staged step mask drops
-            # every ghost set wholesale — ghost duals/X cells are simply
-            # never visited (the structural fixed-point argument).
-            act = sb.active[0]
-            if self.n_real < self.n:
-                act = act & (sb.kN[0] < self.n_real)
-            # Projection gains: g = (1/w)/eps, staged so the inner step
-            # never divides; dinv = 1/(sum of the triplet's three gains)
-            # makes theta a single multiply (ref.py::fused_step).
-            g_row = (one / sb.w_row[0]) / epsc
-            g_col = (one / sb.w_col[0]) / epsc
-            g_ikp = (one / sb.w_ikp[0]) / epsc  # (D, 2, Cl)
-            g_sel = np.where(
-                sb.seg[0], g_ikp[:, 1][:, None, :], g_ikp[:, 0][:, None, :]
-            ).astype(npdt)
-            dinv = (one / (g_row + g_sel + g_col)).astype(npdt)
-            b.update(
-                J=jnp.asarray(sb.J[0]),
-                iN=jnp.asarray(sb.iN[0]),
-                kN=jnp.asarray(sb.kN[0]),
-                act=jnp.asarray(act),
-                seg=jnp.asarray(sb.seg[0]),
-                g_row=jnp.asarray(g_row),
-                g_col=jnp.asarray(g_col),
-                g_sel=jnp.asarray(g_sel),
-                dinv=jnp.asarray(dinv),
-            )
+        path re-derives them at runtime and must not pay their memory).
+        Each bucket's slabs are built on the host (``repro.stage.slabs``)
+        and put on the device (``repro.stage.put``) in turn, so the host
+        holds one bucket's gains at a time; the last put span waits for
+        every transfer."""
+        with obs.span("repro.stage.put"):
+            buckets = [
+                dict(
+                    i=jnp.asarray(bl.i[0], jnp.int32),
+                    k=jnp.asarray(bl.k[0], jnp.int32),
+                    s=jnp.asarray(bl.sizes[0], jnp.int32),
+                    i2=jnp.asarray(bl.i2[0], jnp.int32),
+                    k2=jnp.asarray(bl.k2[0], jnp.int32),
+                    s2=jnp.asarray(bl.sizes2[0], jnp.int32),
+                    T=bl.T,
+                )
+                for bl in self.layout.buckets
+            ]
+        if self.fused:
+            npdt = np.dtype(self.dtype)
+            one = npdt.type(1.0)
+            epsc = npdt.type(self.p.eps)
+            with obs.span("repro.stage.slabs"):
+                stage = sched.build_static_stage(self.layout, self.p.w, npdt)
+            for b, sb in zip(buckets, stage):
+                with obs.span("repro.stage.slabs"):
+                    # Ghost padding (DESIGN.md §8): a triplet is real iff
+                    # its largest index kN < n_real, so the staged step
+                    # mask drops every ghost set wholesale — ghost duals/X
+                    # cells are simply never visited (the structural
+                    # fixed-point argument).
+                    act = sb.active[0]
+                    if self.n_real < self.n:
+                        act = act & (sb.kN[0] < self.n_real)
+                    # Projection gains: g = (1/w)/eps, staged so the inner
+                    # step never divides; dinv = 1/(sum of the triplet's
+                    # three gains) makes theta a single multiply
+                    # (ref.py::fused_step).
+                    g_row = (one / sb.w_row[0]) / epsc
+                    g_col = (one / sb.w_col[0]) / epsc
+                    g_ikp = (one / sb.w_ikp[0]) / epsc  # (D, 2, Cl)
+                    g_sel = np.where(
+                        sb.seg[0], g_ikp[:, 1][:, None, :],
+                        g_ikp[:, 0][:, None, :],
+                    ).astype(npdt)
+                    dinv = (one / (g_row + g_sel + g_col)).astype(npdt)
+                with obs.span("repro.stage.put"):
+                    b.update(
+                        J=jnp.asarray(sb.J[0]),
+                        iN=jnp.asarray(sb.iN[0]),
+                        kN=jnp.asarray(sb.kN[0]),
+                        act=jnp.asarray(act),
+                        seg=jnp.asarray(sb.seg[0]),
+                        g_row=jnp.asarray(g_row),
+                        g_col=jnp.asarray(g_col),
+                        g_sel=jnp.asarray(g_sel),
+                        dinv=jnp.asarray(dinv),
+                    )
+        with obs.span("repro.stage.put"):
+            jax.block_until_ready(buckets)
         return buckets
 
     def _staged_arrays(self) -> list[dict]:
@@ -373,14 +387,16 @@ class ParallelSolver(SolverRuntime):
         f, ypair, ybox = st.f, st.ypair, st.ybox
         mask = self._mask
         if self.p.has_f:
-            x2, f2, ypair = self._pair_step(x, f, ypair)
-            x = jnp.where(mask, x2, x)
-            f = jnp.where(mask, f2, f)
-            ypair = jnp.where(mask[None], ypair, 0)
+            with jax.named_scope("repro.pass.pair"):
+                x2, f2, ypair = self._pair_step(x, f, ypair)
+                x = jnp.where(mask, x2, x)
+                f = jnp.where(mask, f2, f)
+                ypair = jnp.where(mask[None], ypair, 0)
         if self.p.box is not None:
-            x2, ybox = self._box_step(x, ybox)
-            x = jnp.where(mask, x2, x)
-            ybox = jnp.where(mask[None], ybox, 0)
+            with jax.named_scope("repro.pass.box"):
+                x2, ybox = self._box_step(x, ybox)
+                x = jnp.where(mask, x2, x)
+                ybox = jnp.where(mask[None], ybox, 0)
         return ParallelState(x, f, new_yd, ypair, ybox, st.passes + 1)
 
     # ------------------------------------------------------ multi-pass run

@@ -220,12 +220,19 @@ def _diag_step(xv, out, lane, geo, segv, yv, grow, gcol, gsel, dinv, actv,
     """One diagonal for B instances: gather (XLA), ``sweep``, scatter
     (XLA). ``xv`` is the gather source, ``out`` the scatter target (the
     same values in in-place mode; zeros in delta mode)."""
-    rowb, colb, xikp = jax.vmap(lambda xb: _gather_diag(xb, lane, geo))(xv)
-    nrow, ncol, nxikp, ny = sweep(
-        rowb, colb, xikp, yv, grow, gcol, gsel, dinv, actv, segv
-    )
+    with jax.named_scope("repro.bucket.gather"):
+        rowb, colb, xikp = jax.vmap(
+            lambda xb: _gather_diag(xb, lane, geo)
+        )(xv)
+    with jax.named_scope("repro.bucket.sweep"):
+        nrow, ncol, nxikp, ny = sweep(
+            rowb, colb, xikp, yv, grow, gcol, gsel, dinv, actv, segv
+        )
     scatter = lambda ob, *a: _scatter_diag(ob, lane, geo, *a)
-    out = jax.vmap(scatter)(out, actv, rowb, colb, xikp, nrow, ncol, nxikp)
+    with jax.named_scope("repro.bucket.scatter"):
+        out = jax.vmap(scatter)(
+            out, actv, rowb, colb, xikp, nrow, ncol, nxikp
+        )
     return out, ny
 
 
@@ -239,6 +246,7 @@ def _bucket_loop(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
         a, d, ax, keepdims=False
     )
 
+    @jax.named_scope("repro.bucket")
     def diag(d, carry):
         xc, out, y = carry
         out2, ny = _diag_step(

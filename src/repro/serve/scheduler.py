@@ -38,9 +38,9 @@ batch reuses it. ``warmup(family)`` pre-compiles the runner for every
 configured ladder rung up front (in continuous mode, the chunk stepper
 and the refill merge too), so the first real batch of a prewarmed slot
 dispatches warm. ``stats()`` reports the cache hit rate, warm/cold
-dispatch counts, throughput, slot occupancy, per-bucket queue-depth
-high-water marks, and refill/chunk counters — the numbers the serve
-benchmark and CI smoke legs grep for.
+dispatch counts, summed solve time, slot occupancy, per-bucket
+queue-depth high-water marks, and refill/chunk counters — the numbers
+the serve benchmark and CI smoke legs grep for.
 
 **Fault tolerance** (DESIGN.md §11). ``submit`` never raises for a
 solvable request and every accepted request reaches exactly one terminal
@@ -958,9 +958,9 @@ class BatchScheduler:
 
     # -------------------------------------------------------------- stats
     def stats(self) -> dict:
-        """Throughput / occupancy / queue / compile-cache / warmth
-        counters. A sync point like ``results()``: waits for in-flight
-        work, so the numbers describe completed dispatches.
+        """Occupancy / queue / compile-cache / warmth counters. A sync
+        point like ``results()``: waits for in-flight work, so the numbers
+        describe completed dispatches.
 
         ``occupancy`` is mode-dependent: drain mode reports real
         instances per dispatched batch slot (how full the batches were);
@@ -996,10 +996,6 @@ class BatchScheduler:
                 "pending": self.pending,
                 "occupancy": occupancy,
                 "solve_time_s": self._solve_time,
-                "throughput_ips": (
-                    self._instances_done / self._solve_time
-                    if self._solve_time > 0 else 0.0
-                ),
                 "sharded_done": self._sharded_done,
                 "sharded_time_s": self._sharded_time,
                 "queue_depth_hwm": dict(self._queue_hwm),

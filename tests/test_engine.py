@@ -6,6 +6,7 @@ stops at exactly the pass the host-driven chunk loop would, and that the
 direct slab→slab re-shard permutation equals the dense round-trip oracle."""
 
 import os
+import re
 import subprocess
 import sys
 import textwrap
@@ -366,3 +367,93 @@ def test_run_until_staged_operand_mode(x64):
     st2, info2 = solver.run_until(st_, tol=0.0, max_passes=7, check_every=3)
     assert info2["passes"] == 7
     np.testing.assert_array_equal(np.asarray(st2.x), np.asarray(st_.x))
+
+
+# ------------------------------------------- the solver's own spans/scopes
+def test_obs_registry_spans_counts_snapshot_reset():
+    from repro import obs
+
+    obs.reset()
+    with obs.span("repro.test.outer"):
+        with obs.span("repro.test.inner"):
+            pass
+    with obs.span("repro.test.inner"):
+        pass
+    obs.count("repro.test.n", 3)
+    obs.count("repro.test.n")
+    snap = obs.snapshot()
+    assert snap["repro.test.outer"]["n"] == 1
+    assert snap["repro.test.inner"]["n"] == 2
+    inner = snap["repro.test.inner"]
+    assert 0 <= inner["first_s"] <= inner["s"]
+    assert snap["repro.test.n"]["n"] == 4
+    snap["repro.test.n"]["n"] = 0  # a copy: the table is unchanged
+    assert obs.snapshot()["repro.test.n"]["n"] == 4
+    obs.reset()
+    assert obs.snapshot() == {}
+
+
+SCOPES = ("repro.bucket", "repro.bucket.gather", "repro.bucket.sweep",
+          "repro.bucket.scatter", "repro.pass.pair", "repro.pass.box",
+          "repro.chunk.probe", "repro.chunk.objective")
+
+
+def test_window_program_names_its_scopes():
+    """The solo window program (run_until's while loop, kernel path) names
+    every layer the trace reduction reads in its op metadata."""
+    solver = ParallelSolver(_problem(12, seed=1, kind="cc"),
+                            use_kernel=True, bucket_diagonals=3)
+    text = solver._until_fn(2, "absolute", 16).lower(
+        solver.init_state(), 0.0, 4).as_text(debug_info=True)
+    names = set(re.findall(r"repro\.[a-z.]*[a-z]", text))
+    assert set(SCOPES) <= names, set(SCOPES) - names
+
+
+#: host round trips of one ``run_until`` call that runs its chunks: the
+#: window program, 5 reads of its results, the objectives program and its
+#: read, the stop decision, and the pass count.
+ROUND_TRIPS = 10
+
+#: device programs the stop decision dispatches on host floats, one per
+#: ``jnp`` op that touches a Python float: inside its one round trip.
+STOP_PROGRAMS = {"absolute": 3, "rel_gap": 6, "plateau": 6}
+
+
+@pytest.mark.parametrize("rule", sorted(STOP_PROGRAMS))
+def test_run_until_counts_its_spans_and_round_trips(tmp_path, rule):
+    """One constructor records the layout span once, and a slabs and a put
+    span per bucket besides the static stage, the lane tables and the
+    final wait; each ``run_until`` call one ``repro.run_until`` span with
+    its launch, wait and harvest, and ``ROUND_TRIPS`` round trips, while
+    the profiler sees the window and objectives programs and the stop
+    decision's ``STOP_PROGRAMS`` launched."""
+    from jax.profiler import ProfileData
+
+    from repro import obs
+
+    obs.reset()
+    solver = ParallelSolver(_problem(10, seed=2, kind="cc"),
+                            use_kernel=True, bucket_diagonals=2)
+    nb = len(solver.layout.buckets)
+    stage = {k: v["n"] for k, v in obs.snapshot().items()}
+    assert stage == {"repro.stage.layout": 1, "repro.stage.slabs": 1 + nb,
+                     "repro.stage.put": 2 + nb}
+    st_, _ = solver.run_until(tol=0.0, max_passes=4, check_every=2,
+                              stop_rule=rule)
+    jax.profiler.start_trace(str(tmp_path))
+    solver.run_until(st_, tol=0.0, max_passes=10, check_every=2,
+                     stop_rule=rule)
+    jax.profiler.stop_trace()
+    snap = obs.snapshot()
+    for name in ("repro.run_until", "repro.run_until.launch",
+                 "repro.run_until.wait", "repro.run_until.harvest"):
+        assert snap[name]["n"] == 2
+    assert snap["repro.run_until.launch"]["first_s"] > 0
+    assert snap["repro.run_until.calls"]["n"] == 2
+    assert snap["repro.run_until.syncs"]["n"] == 2 * ROUND_TRIPS
+    path = next(tmp_path.glob("**/*.xplane.pb"))
+    launched = sum(ev.name == "PjRtCpuExecutable::Execute"
+                   for plane in ProfileData.from_file(str(path)).planes
+                   for line in plane.lines for ev in line.events)
+    assert launched == 2 + STOP_PROGRAMS[rule]
+    assert {k: snap[k]["n"] for k in stage} == stage
