@@ -22,10 +22,15 @@ launch. Gen-3 changes the contract, not the math:
     scatter, because both scatter the same ``where(act, new - old, 0)``
     values into zeros).
 
-Both staging engines walk the bucket's diagonals in one loop, carrying
-the dual slab and updating it in place. Per diagonal they gather the folded X row/column/carry slices in XLA
-(the indexing ``ref.fused_bucket_pass_ref`` uses), sweep them, and
-scatter the act-masked deltas back in XLA. They differ only in the sweep:
+Both modes walk the bucket's diagonals in one loop, carrying
+the dual slab and updating it in place. Per diagonal they gather the
+folded X row/column/carry slices in XLA, sweep them, and add the
+act-masked deltas back in XLA. A single-device bucket (lane f holds set
+f of its diagonal) reads and writes X as dense blocks at offsets fixed
+per diagonal, skewed by a static shear (the band engine); the sharded
+delta path, whose lanes are dealt over devices, gathers and scatters by
+the element index tables of ``ref.fused_bucket_pass_ref`` (the index
+engine). The two modes differ only in the sweep:
 
   * ``mode="tpu"`` (TPU production, the only engine compiled with
     ``interpret=False``): the sweep is a Pallas kernel (``_sweep_kernel``)
@@ -36,7 +41,8 @@ scatter the act-masked deltas back in XLA. They differ only in the sweep:
     in-kernel per-lane gathers of the earlier engine (windows at dynamic
     lane/sublane offsets, single-row dynamic stores, value
     ``dynamic_slice``) are all refused by the TPU compiler (DESIGN.md §10).
-    Nothing of X lives in VMEM and no lane table lives in SMEM.
+    Nothing of X lives in VMEM and no lane table lives in SMEM. The band
+    engine's row blocks are transposed by a second small Pallas call.
   * ``mode="vector"`` (CPU / interpret only): the sweep is
     ``ref.fused_diag_sweep`` vmapped over B. With one lane block (every
     production bucket) the bucket program is plain XLA; with several it
@@ -48,9 +54,9 @@ VMEM budget (tpu mode, per grid step): 16 ``(T, block_c)`` tiles (11 in,
 12.6 MiB at T = 768, block_c = 128. The vector engine holds
 B·n² floats and is CPU-only by construction.
 
-Exactness note shared by both engines: every scatter outside a lane's
+Exactness note shared by both engines: every write outside a lane's
 active cells adds an exact 0.0 (act-masked deltas; carry deltas guarded
-by ``sizes > 0``), so overlapping windows / wrapped padding indices only
+by ``sizes > 0``), so overlapping blocks / wrapped padding indices only
 ever add zeros — and X cells are never -0.0 (they start at +0.0 and only
 accumulate sums), so zero-adds are bitwise no-ops.
 """
@@ -64,6 +70,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro import obs
 from repro.kernels.metric_project.ref import fused_diag_sweep, fused_step
 
 __all__ = ["fused_bucket_pass_pallas"]
@@ -186,6 +193,27 @@ def _vector_sweep(unroll: int):
     return jax.vmap(one, in_axes=(0,) * 9 + (None,))
 
 
+def _transpose_kernel(a_ref, o_ref):
+    o_ref[...] = a_ref[...].T
+
+
+def _transpose_tiles(a, *, interpret: bool):
+    """(B, R, C) -> (B, C, R) as a Pallas call. The tpu engine transposes
+    the band engine's row blocks here: an XLA transpose lets the TPU
+    compiler lay the whole padded X out column-major around the row
+    blocks' writes, two copies of X on every diagonal."""
+    B, R, C = a.shape
+    return pl.pallas_call(
+        _transpose_kernel,
+        grid=(B,),
+        in_specs=[pl.BlockSpec((None, R, C), lambda b: (b, 0, 0))],
+        out_specs=pl.BlockSpec((None, C, R), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, C, R), a.dtype),
+        interpret=interpret,
+        name="band_transpose",
+    )(a)
+
+
 def _gather_diag(xb, lane, geo):
     """Folded row/column slices and the two carries of one diagonal of one
     instance — the gathers of ``ref.fused_bucket_pass_ref``."""
@@ -215,53 +243,222 @@ def _scatter_diag(outb, lane, geo, ab, rowb, colb, xikp, nrow, ncol, nxikp):
     return outb
 
 
-def _diag_step(xv, out, lane, geo, segv, yv, grow, gcol, gsel, dinv, actv,
+def _index_engine(lane, geo):
+    """Gather and scatter of one diagonal by the element index tables
+    ``J/iN/kN``, for any lane deal (the sharded delta path, the tiled
+    vector engine)."""
+
+    def gather(xv):
+        return jax.vmap(lambda xb: _gather_diag(xb, lane, geo))(xv)
+
+    def scatter(out, *args):
+        one = lambda ob, *a: _scatter_diag(ob, lane, geo, *a)
+        return jax.vmap(one)(out, *args)
+
+    return gather, scatter
+
+
+# ---------------------------------------------------------------------------
+# Band engine (DESIGN.md §10): the gathers and scatters of a ``procs = 1``
+# diagonal as dense blocks of X.
+#
+# Diagonal d holds the sets S_{x+c, z-c}, c < C_d, and its lane f holds set
+# f (segment A) and set C_d-1-f (segment B). With r0 = x - s + C_d + 1,
+# s = z - x, every operand of lane f is column f of a block of X read at a
+# per-diagonal offset, "sheared" up by f rows:
+#
+#   row A  X[x+f, x+f+1+t]          block X[x : x+F, x+1 : x+1+T], transposed
+#   row B  X[x+C_d-1-f, r0+f+t]     block X[x+C_d-F : x+C_d, r0 : r0+T+F],
+#                                   rows flipped, transposed
+#   col A  X[x+f+1+t, z-f]          block X[x : x+T+1, z-F+1 : z+1], columns
+#                                   flipped; its row 0 is the carry X[x+f, z-f]
+#   col B  X[r0+f+t, z-C_d+1+f]     block X[r0 : r0+T+F, z-C_d+1 : +F]
+#
+# and the B carry X[x+C_d-1-f, z-C_d+1+f] is row h = 2(s - C_d) of the
+# sheared row-B block (h is the height of every paired lane). F is the
+# bucket's lane count. A block row or column outside a lane's set holds
+# other cells of X (or padding): the sweep reads them only at steps
+# ``act`` masks, and the write-back adds an exact +0.0 there.
+# ---------------------------------------------------------------------------
+
+
+def _band_pads(n: int, T: int, F: int):
+    """(top, bottom, left, right) padding of X under which no block of any
+    diagonal of a (T, F) bucket leaves the padded matrix.
+
+    ``lax.dynamic_slice`` clamps a start that would run off the end, which
+    moves the whole window. The bounds follow from 0 <= x, z <= n-1,
+    2 <= s <= min(n-1, T+1, 4F+1) (lane 0 is s-1 steps long, and a lane
+    holds at most two sets) and C_d = floor(s/2) >= 1: the most negative
+    start is r0 >= 1 - ceil(s/2) (segment B), or 1 - F for the row-B rows
+    and 3 - F for the col-A columns; the B blocks, T+F long, end at most
+    T+F-3 past n-1."""
+    s = min(n - 1, T + 1, 4 * F + 1)
+    half = (s + 1) // 2 - 1
+    far = max(T + F - 3, F - 1, 0)
+    return max(F - 1, half, 0), far, max(F - 3, half, 0), far
+
+
+def _shear(a, sign: int):
+    """Shift column f of ``a`` (..., R, F) along R by ``sign``·f rows,
+    filling with zeros: ``sign = -1`` gives out[t, f] = a[t + f, f],
+    ``+1`` gives out[t, f] = a[t - f, f]. A barrel of static shifts and
+    selects, one per bit of F - 1."""
+    f = jnp.arange(a.shape[-1])
+    zero = jnp.zeros((), a.dtype)
+    for b in range((a.shape[-1] - 1).bit_length()):
+        k = sign << b
+        cfg = [(0, 0, 0)] * a.ndim
+        cfg[-2] = (k, -k, 0)
+        a = jnp.where((f >> b) & 1 == 1, jax.lax.pad(a, zero, cfg), a)
+    return a
+
+
+def _band_blocks(x, z, T: int, F: int):
+    """The blocks of the diagonal whose first set is S_{x,z}: name ->
+    (row, column) of its corner in X, name -> its (rows, columns), and the
+    row h of the sheared row-B block that holds the B carries."""
+    s = z - x
+    c = (s - 2) // 2 + 1
+    r0 = x - s + c + 1
+    at = {"row_a": (x, x + 1), "row_b": (x + c - F, r0),
+          "col_a": (x, z - F + 1), "col_b": (r0, z - c + 1)}
+    size = {"row_a": (F, T), "row_b": (F, T + F),
+            "col_a": (T + 1, F), "col_b": (T + F, F)}
+    return at, size, 2 * (s - c)
+
+
+def _band_engine(lane, segv, pads, transpose):
+    """Gather and scatter of one diagonal of a ``procs = 1`` bucket as
+    dense blocks of the padded batch ``xp`` (B, n + pads, n + pads); see
+    the table above. Nothing here is indexed per element. ``pads`` is the
+    (top, left) padding; ``transpose`` swaps the last two axes of a
+    (B, R, C) array."""
+    top, left = pads
+    T, F = segv.shape
+    i1, k1, s1, _, _, s2 = lane
+    at, size, h = _band_blocks(i1[0], k1[0], T, F)
+    tr = transpose
+    ident = lambda a: a
+    # block -> its (R, F) frame (lane f in column f), and back
+    to_frame = {
+        "row_a": tr, "row_b": lambda a: tr(a[:, ::-1]),
+        "col_a": lambda a: a[:, :, ::-1], "col_b": ident,
+    }
+    from_frame = {
+        "row_a": tr, "row_b": lambda a: tr(a)[:, ::-1],
+        "col_a": lambda a: a[:, :, ::-1], "col_b": ident,
+    }
+
+    def start(name):
+        r, col = at[name]
+        return (jnp.int32(0), r + top, col + left)
+
+    def gather(xp):
+        B = xp.shape[0]
+        fr = {
+            k: _shear(to_frame[k](jax.lax.dynamic_slice(
+                xp, start(k), (B,) + size[k])), -1)
+            for k in at
+        }
+        rowb = jnp.where(segv, fr["row_b"][:, :T], fr["row_a"])
+        colb = jnp.where(segv, fr["col_b"][:, :T], fr["col_a"][:, 1:])
+        xb = jax.lax.dynamic_index_in_dim(fr["row_b"], h, 1, keepdims=False)
+        return rowb, colb, jnp.stack([fr["col_a"][:, 0], xb], axis=1)
+
+    def scatter(xp, ab, rowb, colb, xikp, nrow, ncol, nxikp):
+        live_a, live_b = ab & ~segv, ab & segv
+        d = lambda new, old, m: jnp.where(m, new - old, 0)
+        tail = lambda a: jnp.pad(a, ((0, 0), (0, F), (0, 0)))
+        dxa = d(nxikp[:, 0], xikp[:, 0], s1 > 0)
+        dxb = d(nxikp[:, 1], xikp[:, 1], s2 > 0)
+        fr = {
+            "row_a": d(nrow, rowb, live_a),
+            # Row h of the B frame is past every lane's last step: only
+            # the B carry lives there. (A single-set diagonal has no B
+            # segment; its dxb is 0 wherever row h lands.)
+            "row_b": jax.lax.dynamic_update_index_in_dim(
+                tail(d(nrow, rowb, live_b)), dxb, h, 1),
+            "col_a": jnp.concatenate(
+                [dxa[:, None], d(ncol, colb, live_a)], axis=1),
+            "col_b": tail(d(ncol, colb, live_b)),
+        }
+        # One block after another: blocks of a diagonal may overlap, and
+        # outside its set each adds +0.0, so live cells end as the index
+        # engine's scatter-add leaves them.
+        for k in at:
+            u = from_frame[k](_shear(fr[k], 1))
+            blk = jax.lax.dynamic_slice(xp, start(k), u.shape)
+            xp = jax.lax.dynamic_update_slice(xp, blk + u, start(k))
+        return xp
+
+    return gather, scatter
+
+
+def _diag_step(xv, out, engine, segv, yv, grow, gcol, gsel, dinv, actv,
                sweep):
-    """One diagonal for B instances: gather (XLA), ``sweep``, scatter
-    (XLA). ``xv`` is the gather source, ``out`` the scatter target (the
-    same values in in-place mode; zeros in delta mode)."""
+    """One diagonal for B instances: the engine's gather, ``sweep``, the
+    engine's scatter. ``xv`` is the gather source, ``out`` the scatter
+    target (the same values in in-place mode; zeros in delta mode)."""
+    gather, scatter = engine
     with jax.named_scope("repro.bucket.gather"):
-        rowb, colb, xikp = jax.vmap(
-            lambda xb: _gather_diag(xb, lane, geo)
-        )(xv)
+        rowb, colb, xikp = gather(xv)
     with jax.named_scope("repro.bucket.sweep"):
         nrow, ncol, nxikp, ny = sweep(
             rowb, colb, xikp, yv, grow, gcol, gsel, dinv, actv, segv
         )
-    scatter = lambda ob, *a: _scatter_diag(ob, lane, geo, *a)
     with jax.named_scope("repro.bucket.scatter"):
-        out = jax.vmap(scatter)(
-            out, actv, rowb, colb, xikp, nrow, ncol, nxikp
-        )
+        out = scatter(out, actv, rowb, colb, xikp, nrow, ncol, nxikp)
     return out, ny
 
 
 def _bucket_loop(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
-                 geom, *, sweep, out_delta):
+                 geom, *, sweep, transpose, out_delta):
     """One loop over the bucket's diagonals, each a ``_diag_step`` on the
-    whole batch — the bucket program of both engines. The dual slab is
-    carried and updated in place, one diagonal at a time."""
-    D = yslab.shape[1]
+    whole batch — the bucket program of both sweeps. The dual slab is
+    carried and updated in place, one diagonal at a time.
+
+    In-place mode reads and writes X by the band engine, on a padded copy
+    of X that the loop carries; delta mode (lanes dealt round-robin over
+    devices, so a device's lanes hold no contiguous sets) by the index
+    engine."""
+    n = x.shape[1]
+    D, T, C = seg.shape
     at = lambda a, ax, d: jax.lax.dynamic_index_in_dim(
         a, d, ax, keepdims=False
     )
+    if not out_delta:
+        top, bottom, left, right = _band_pads(n, T, C)
+        with jax.named_scope("repro.bucket.gather"):
+            x = jnp.pad(x, ((0, 0), (top, bottom), (left, right)))
 
     @jax.named_scope("repro.bucket")
     def diag(d, carry):
-        xc, out, y = carry
-        out2, ny = _diag_step(
-            xc, out, at(lanes, 1, d), tuple(at(g, 0, d) for g in geom),
-            at(seg, 0, d) != 0, at(y, 1, d), at(g_row, 1, d),
+        out, y = carry
+        segv = at(seg, 0, d) != 0
+        lane = at(lanes, 1, d)
+        if out_delta:
+            engine = _index_engine(lane, tuple(at(g, 0, d) for g in geom))
+        else:
+            engine = _band_engine(lane, segv, (top, left), transpose)
+        out, ny = _diag_step(
+            # Delta mode gathers from the pristine X (D == 1 by contract);
+            # in-place mode threads the iterate.
+            x if out_delta else out, out, engine,
+            segv, at(y, 1, d), at(g_row, 1, d),
             at(g_col, 1, d), at(g_sel, 1, d), at(dinv, 1, d),
-            at(act, 1, d) != 0, sweep,
+            # The barrier makes the diagonal's slice of ``act`` a value of
+            # its own: without it the TPU compiler copies the whole
+            # bucket's act slab to another layout on every diagonal.
+            jax.lax.optimization_barrier(at(act, 1, d)) != 0, sweep,
         )
-        y = jax.lax.dynamic_update_index_in_dim(y, ny, d, 1)
-        # Delta mode gathers from the pristine X every diagonal (D == 1
-        # by contract); in-place mode threads the iterate.
-        return (xc if out_delta else out2, out2, y)
+        return out, jax.lax.dynamic_update_index_in_dim(y, ny, d, 1)
 
     out0 = jnp.zeros_like(x) if out_delta else x
-    _, nx, ny = jax.lax.fori_loop(0, D, diag, (x, out0, yslab))
+    nx, ny = jax.lax.fori_loop(0, D, diag, (out0, yslab))
+    if not out_delta:
+        with jax.named_scope("repro.bucket.scatter"):
+            nx = nx[:, top:top + n, left:left + n]
     return nx, ny
 
 
@@ -299,8 +496,9 @@ def _fused_kernel_vector(
     ).reshape(6, block_c)
     xv = x_ref[...] if out_delta else ox_ref[...]
     base = ox_ref[...] if out_delta else xv
+    segv = seg_ref[0] != 0
     nxv, ny = _diag_step(
-        xv, base, lane, geom_ref[...][:, 0], seg_ref[0] != 0,
+        xv, base, _index_engine(lane, geom_ref[...][:, 0]), segv,
         y_ref[...][:, 0], grow_ref[...][:, 0], gcol_ref[...][:, 0],
         gsel_ref[...][:, 0], dinv_ref[...][:, 0], act_ref[...][:, 0] != 0,
         _vector_sweep(unroll),
@@ -421,12 +619,18 @@ def fused_bucket_pass_pallas(
         raise ValueError("out_delta requires a single-diagonal call (D=1)")
     geom = tuple(g.astype(jnp.int32) for g in geom)
     operands = (x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg, geom)
+    tiled = mode == "vector" and block_c < C
+    # Counted once per trace of a bucket program: which engine it lowered to.
+    obs.count("repro.bucket.engine."
+              + ("index" if out_delta or tiled else "band"))
+    transpose = lambda a: jnp.swapaxes(a, 1, 2)
     if mode == "tpu":
         sweep = functools.partial(
             _sweep_tiles, block_c=block_c, interpret=interpret,
             unroll=unroll, alias=in_place,
         )
-    elif block_c >= C:
+        transpose = functools.partial(_transpose_tiles, interpret=interpret)
+    elif not tiled:
         # Single lane block: the vector engine runs as plain XLA — a
         # pallas grid of one step would add only whole-buffer copies
         # around the identical body.
@@ -435,4 +639,5 @@ def fused_bucket_pass_pallas(
         return _vector_tiled_pass(
             *operands, block_c=block_c, unroll=unroll, out_delta=out_delta
         )
-    return _bucket_loop(*operands, sweep=sweep, out_delta=out_delta)
+    return _bucket_loop(*operands, sweep=sweep, transpose=transpose,
+                        out_delta=out_delta)

@@ -66,22 +66,50 @@ def test_fused_pass_matches_oracle_cc_lp(x64):
     np.testing.assert_allclose(np.asarray(st.f), st_ser.f, atol=1e-5, rtol=1e-5)
 
 
-def test_megakernel_matches_fused_ref_bitwise():
-    """The megakernel and the jnp reference share fused_step op-for-op, so
-    X must agree bitwise in float32 on a non-trivial dual state."""
+BAND_CASES = [(n, nb) for n in (5, 14, 23, 40) for nb in (1, 3, 6)]
+
+
+def _assert_band_matches_index(p, dtype, buckets, index_engine=False):
+    """Bucket by bucket, on the second of two passes (non-zero duals),
+    the band engine (``ops.fused_bucket_pass``) leaves X bitwise as the
+    jnp reference's element gathers and scatters do (and, with
+    ``index_engine``, as the index engine of the tiled vector path), and
+    every live dual cell as the reference does. The cases cover odd and
+    even set counts, padded lanes, single-set diagonals and both
+    families' first and last diagonals."""
     from repro.kernels.metric_project import ops
     from repro.kernels.metric_project.ref import fused_bucket_pass_ref
 
-    n = 16
-    p = _l2_problem(n, seed=9)
-    solver = ParallelSolver(p, bucket_diagonals=2)
-    st = solver.run(passes=2)  # non-zero duals
-    x = st.x
+    solver = ParallelSolver(p, dtype=dtype, bucket_diagonals=buckets)
+    ref = jax.jit(fused_bucket_pass_ref)
+    st = solver.init_state()
+    x, yd = st.x, []
     for b, yb in zip(solver._buckets, st.yd):
-        rx, ry = fused_bucket_pass_ref(x, yb, b)
+        x, yb = ref(x, yb, b)
+        yd.append(yb)
+    live = sched.slab_valid_masks(solver.layout)
+    for b, yb, m in zip(solver._buckets, yd, live):
+        rx, ry = ref(x, yb, b)
         kx, ky = ops.fused_bucket_pass(x, yb, b)
         np.testing.assert_array_equal(np.asarray(rx), np.asarray(kx))
+        np.testing.assert_array_equal(np.asarray(ry)[m[0]],
+                                      np.asarray(ky)[m[0]])
+        if index_engine:
+            ix, _ = _engine_bucket_pass("vector-tiled", x, yb, b, b["act"])
+            np.testing.assert_array_equal(np.asarray(ix), np.asarray(kx))
         x = rx
+    assert np.abs(np.asarray(x) - np.asarray(st.x)).max() > 0
+
+
+@pytest.mark.parametrize("n,buckets", [(16, 2)] + BAND_CASES)
+def test_megakernel_matches_fused_ref_bitwise(n, buckets):
+    """The megakernel and the jnp reference share fused_step op-for-op, so
+    X must agree bitwise in float32 on a non-trivial dual state."""
+    p = _l2_problem(n, seed=9)
+    _assert_band_matches_index(p, np.float32, buckets,
+                               index_engine=(n, buckets) == (16, 2))
+    if (n, buckets) != (16, 2):
+        return
     # dual slabs agree on every real (non-padding) cell via the dense maps
     a = ParallelSolver(p, bucket_diagonals=2, use_kernel=False).run(passes=3)
     b = ParallelSolver(p, bucket_diagonals=2, use_kernel=True).run(passes=3)
@@ -92,44 +120,89 @@ def test_megakernel_matches_fused_ref_bitwise():
 
 
 # ------------------------------------------------- gen-3 megakernel (§10)
-def test_megakernel_solo_bitwise_f64(x64):
+@pytest.mark.parametrize("n,buckets", [(14, 2)] + BAND_CASES)
+def test_megakernel_solo_bitwise_f64(x64, n, buckets):
     """Gen-3 solo path in float64 interpret mode: bitwise-equal X to
     ``ref.fused_bucket_pass_ref`` bucket-for-bucket (the staging engines
     reorganize execution, never the arithmetic)."""
-    from repro.kernels.metric_project import ops
-    from repro.kernels.metric_project.ref import fused_bucket_pass_ref
+    _assert_band_matches_index(_l2_problem(n, seed=21), np.float64, buckets)
 
-    p = _l2_problem(14, seed=21)
-    solver = ParallelSolver(p, dtype=np.float64, bucket_diagonals=2)
-    st = solver.run(passes=2)  # non-zero duals
-    x = st.x
+
+@given(n=st.integers(3, 80), nb=st.integers(1, 8))
+@settings(max_examples=30, deadline=None)
+def test_property_band_blocks_stay_inside_the_padding(n, nb):
+    """``lax.dynamic_slice`` clamps a start that runs off the array, which
+    moves the whole window: every block of every diagonal of a
+    single-device bucket must lie inside X padded by ``_band_pads``."""
+    from repro.kernels.metric_project import fused_pass
+
+    for bl in sched.build_layout(n, num_buckets=nb, procs=1).buckets:
+        T, F = bl.T, bl.lanes
+        top, bottom, left, right = fused_pass._band_pads(n, T, F)
+        for r in range(bl.num_diagonals):
+            at, size, _ = fused_pass._band_blocks(
+                int(bl.i[0, r, 0]), int(bl.k[0, r, 0]), T, F)
+            for k, (row, col) in at.items():
+                rows, cols = size[k]
+                assert -top <= row and row + rows <= n + bottom, (k, r)
+                assert -left <= col and col + cols <= n + right, (k, r)
+
+
+def test_bucket_programs_count_their_engine():
+    """Each bucket program counts, when traced, the engine it lowered to:
+    the band engine for a single-device bucket, the index engine for the
+    sharded delta path (one diagonal of lanes dealt over devices) and the
+    tiled vector engine."""
+    from repro import obs
+    from repro.kernels.metric_project import fused_pass
+
+    p = _l2_problem(12, seed=4)
+    solver = ParallelSolver(p, bucket_diagonals=3)
+    st = solver.run(passes=1)
+    n_of = lambda: {e: obs.snapshot().get(f"repro.bucket.engine.{e}",
+                                          {"n": 0})["n"]
+                    for e in ("band", "index")}
+    before = n_of()
     for b, yb in zip(solver._buckets, st.yd):
-        rx, _ = fused_bucket_pass_ref(x, yb, b)
-        kx, _ = ops.fused_bucket_pass(x, yb, b)
-        np.testing.assert_array_equal(np.asarray(rx), np.asarray(kx))
-        x = rx
+        _engine_bucket_pass("vector", st.x, yb, b, b["act"])
+    mid = n_of()
+    assert mid == {"band": before["band"] + 3, "index": before["index"]}
+    b, d = solver._buckets[0], 0
+    take = lambda *keys: jnp.stack([b[k][d] for k in keys])
+    fused_pass.fused_bucket_pass_pallas(
+        st.x[None], st.yd[0][None, d:d + 1],
+        take("i", "k", "s", "i2", "k2", "s2")[:, None],
+        *(b[k][None, d:d + 1] for k in ("g_row", "g_col", "g_sel", "dinv",
+                                          "act")),
+        b["seg"][d:d + 1], take("J", "iN", "kN")[:, None], out_delta=True,
+    )
+    _engine_bucket_pass("vector-tiled", st.x, st.yd[0], b, b["act"])
+    assert n_of() == {"band": mid["band"], "index": mid["index"] + 2}
 
 
-def test_megakernel_batched_mixed_ghost_bitwise(x64):
-    """One (B=4, ...) megakernel call per bucket — mixed-n slots with
+@pytest.mark.parametrize("sizes", [(12, 9, 12, None), (12, 7, None)],
+                         ids=["B4", "B3"])
+def test_megakernel_batched_mixed_ghost_bitwise(x64, sizes):
+    """One (B, ...) megakernel call per bucket — mixed-n slots with
     ghost padding and one all-ghost empty slot — must be bitwise-equal to
     the vmapped jnp fused reference, end-to-end through ``run_until``
     (X, per-instance pass counters, stopping vectors, dual stats)."""
     from repro.serve.batching import BatchedSolver
     from repro.serve.buckets import family_of
 
-    ps = [_l2_problem(12, seed=1), _l2_problem(9, seed=2),
-          _l2_problem(12, seed=3), None]
+    ps = [None if m is None else _l2_problem(m, seed=i + 1)
+          for i, m in enumerate(sizes)]
+    B = len(ps)
     fam = family_of(ps[0], np.float64)
-    ref = BatchedSolver(12, 4, fam, num_buckets=3)
-    ker = BatchedSolver(12, 4, fam, num_buckets=3, use_kernel=True)
+    ref = BatchedSolver(12, B, fam, num_buckets=3)
+    ker = BatchedSolver(12, B, fam, num_buckets=3, use_kernel=True)
     inst = ref.stack(ps)
     sta, ia = ref.run_until(inst, tol=1e-5, max_passes=30, check_every=5)
     stb, ib = ker.run_until(inst, tol=1e-5, max_passes=30, check_every=5)
     np.testing.assert_array_equal(np.asarray(sta.x), np.asarray(stb.x))
     np.testing.assert_array_equal(ia["passes"], ib["passes"])
     np.testing.assert_array_equal(ia["max_violation"], ib["max_violation"])
-    assert ib["converged"][3]  # the empty slot converges immediately
+    assert ib["converged"][B - 1]  # the empty slot converges immediately
     da, db = ref.dual_stats(sta, inst), ker.dual_stats(stb, inst)
     for key in da:
         np.testing.assert_array_equal(da[key], db[key])
@@ -336,13 +409,20 @@ def test_static_stage_preserves_zero_weights_on_active_cells():
 
 
 # ------------------------------------------------------ multi-pass runner
-def test_multi_pass_runner_equals_repeated_single_pass():
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["fused-ref", "fused-megakernel"])
+def test_multi_pass_runner_equals_repeated_single_pass(use_kernel):
     """One scan over P passes must produce exactly the same state as P
-    single-pass runs (the scan only removes dispatch, never reorders)."""
+    single-pass runs (the scan only removes dispatch, never reorders);
+    through the band engine it leaves X as the jnp reference does."""
     n = 13
     p = _l2_problem(n, seed=6)
-    solver = ParallelSolver(p, bucket_diagonals=3)
+    solver = ParallelSolver(p, bucket_diagonals=3, use_kernel=use_kernel)
     st_scan = solver.run(passes=4)
+    if use_kernel:
+        st_ref = ParallelSolver(p, bucket_diagonals=3).run(passes=4)
+        np.testing.assert_array_equal(np.asarray(st_scan.x),
+                                      np.asarray(st_ref.x))
     st_loop = solver.init_state()
     for _ in range(4):
         st_loop = solver.run(st_loop, passes=1)

@@ -180,3 +180,40 @@ def test_slab_dims_match_layout(n, nb, procs):
     want = [(b.slab_shape[1], b.slab_shape[3], b.slab_shape[4])
             for b in lay.buckets]
     assert sched.slab_dims(n, num_buckets=nb, procs=procs) == want
+
+
+def _fold_is_contiguous(bl, dev: int = 0) -> bool:
+    """Whether every live slot f of device ``dev``, on every diagonal of
+    ``bl``, holds i1 = x+f, k1 = z-f, i2 = x+C-1-f, k2 = z-C+1+f, with
+    (x, z) the diagonal's first set and C its set count: the band
+    engine's precondition (fused_pass._band_engine)."""
+    for r in range(bl.num_diagonals):
+        i1, k1, i2, k2 = (a[dev, r] for a in (bl.i, bl.k, bl.i2, bl.k2))
+        f = np.flatnonzero(i1 >= 0)
+        x, z = int(bl.i[0, r, 0]), int(bl.k[0, r, 0])
+        c = (z - x - 2) // 2 + 1
+        p = i2[f] >= 0  # paired lanes (an odd diagonal's middle set is not)
+        if not (np.array_equal(i1[f], x + f) and np.array_equal(k1[f], z - f)
+                and np.array_equal(i2[f][p], (x + c - 1 - f)[p])
+                and np.array_equal(k2[f][p], (z - c + 1 + f)[p])):
+            return False
+    return True
+
+
+@given(n=st.integers(3, 60), nb=st.integers(1, 6))
+@settings(max_examples=25, deadline=None)
+def test_property_single_device_fold_is_contiguous(n, nb):
+    """With one device, lane f of a diagonal holds set f and its partner
+    C-1-f, so a lane's row and column slices are rows and columns of
+    dense blocks of X at offsets fixed per diagonal."""
+    for bl in sched.build_layout(n, num_buckets=nb, procs=1).buckets:
+        assert _fold_is_contiguous(bl)
+
+
+def test_dealt_fold_is_not_contiguous():
+    """Dealt round robin over four devices, a device's slots hold lanes
+    f, f+4, ...: its sets are strided, which is why the sharded delta
+    path keeps the element-indexed engine."""
+    lay = sched.build_layout(24, num_buckets=2, procs=4)
+    assert not all(_fold_is_contiguous(bl, dev) for bl in lay.buckets
+                   for dev in range(4))

@@ -10,6 +10,7 @@ kernel functions are called directly (``ops`` would ask
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +82,10 @@ def _bucket(n, num_buckets=6, procs=1, which="first"):
 )
 def test_fused_pass_compiles_for_v5e(one_chip, n, batch, procs, which,
                                      delta):
+    """The bucket program compiles and fits one chip. A single-device
+    bucket reads and writes X as dense blocks (the band engine): no XLA
+    gather or scatter, and no custom fusion, which is how the TPU compiler
+    lowers them. The sharded delta path keeps the element-indexed engine."""
     D, T, C = _bucket(n, procs=procs, which=which)
     if delta:
         D = 1
@@ -93,13 +98,19 @@ def test_fused_pass_compiles_for_v5e(one_chip, n, batch, procs, which,
         tc, tc, tc, tc, f(batch, D, T, C, dt=jnp.bool_),
         f(D, T, C, dt=jnp.bool_), f(3, D, T, C, dt=jnp.int32),
     )
-    _compiled(
+    text = _compiled(
         functools.partial(
             fused_bucket_pass_pallas, mode="tpu", interpret=False,
             in_place=True, out_delta=delta,
         ),
         *args,
-    )
+    ).as_text()
+    indexed = re.findall(r" (gather|scatter)\(", text)
+    if delta:
+        assert {"gather", "scatter"} <= set(indexed)
+    else:
+        assert not indexed
+        assert "kind=kCustom" not in text
 
 
 def test_violation_kernel_compiles_for_v5e(one_chip):
