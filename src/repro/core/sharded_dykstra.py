@@ -25,7 +25,11 @@ executed replicated (identical on every device; no communication).
 Collective cost: one (n, n) psum per diagonal, ~2n psums per pass. The
 per-device compute is O(n^3 / p) — the solver becomes compute-bound once
 n / p is large, which is the trillion-constraint regime the paper targets
-(see EXPERIMENTS.md §Dry-run for the 512-chip memory/collective analysis).
+(PERF.md §4-6 give the merge's share of a four-chip pass at n = 1024).
+The merge is named for the trace: each diagonal's all-reduce (and, in
+psum mode, the add of its sum to X) runs under the device scope
+``repro.shard.merge``, and each traced bucket program adds the bytes it
+all-reduces to the counter ``repro.shard.merge.bytes``.
 
 **Fused-pass execution** (DESIGN.md §9, the default): the per-device sweep
 consumes staged *projection gains* ``g = (1/w)/eps`` and ``dinv``
@@ -55,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core import metrics_device, schedule as sched
 from repro.core.engine import SolverRuntime
 from repro.core.parallel_dykstra import folded_geometry
@@ -153,65 +158,72 @@ class ShardedSolver(SolverRuntime):
         self.num_buckets = num_buckets
         # Schedule-native dual layout, shared with ParallelSolver and the
         # elastic re-sharder (DESIGN.md §3).
-        self.layout = sched.build_layout(
-            self.n, num_buckets=num_buckets, procs=self.nproc
-        )
+        with obs.span("repro.stage.layout"):
+            self.layout = sched.build_layout(
+                self.n, num_buckets=num_buckets, procs=self.nproc
+            )
         self._w = jnp.asarray(problem.w, dtype)
         self._d = jnp.asarray(problem.d, dtype)
         self._wf = jnp.asarray(problem.w_f, dtype) if problem.has_f else None
         self._mask = jnp.triu(jnp.ones((self.n, self.n), bool), k=1)
-        # Static staging (DESIGN.md §4): folded geometry, step masks and
-        # gathered weight slabs are pass-invariant — precomputed once and
-        # sharded on the device axis like the dual slabs, so the per-device
-        # scan body below does no index math and no weight gathers.
-        npdt = np.dtype(dtype)
-        stage = sched.build_static_stage(self.layout, problem.w, npdt)
-        shard = NamedSharding(mesh, P(AXIS))
-        put = lambda a: jax.device_put(jnp.asarray(a), shard)
-        self._work_dev = []
-        for bl, sb in zip(self.layout.buckets, stage):
-            work = {
-                key: put(getattr(bl, key))
-                for key in ("i", "k", "sizes", "i2", "k2", "sizes2")
-            } | {
-                "J": put(sb.J),
-                "iN": put(sb.iN),
-                "kN": put(sb.kN),
-                "act": put(sb.active),
-                "seg": put(sb.seg),
-                "T": bl.T,
-            }
-            if self._fused_sweep:
-                # Projection gains (DESIGN.md §4), staged with the procs
-                # axis and sharded like the dual slabs — the exact
-                # expressions of ParallelSolver._stage_buckets, so the
-                # per-step math is shared bit-for-bit with the
-                # single-device fused path.
-                one = npdt.type(1.0)
-                epsc = npdt.type(problem.eps)
-                g_row = (one / sb.w_row) / epsc
-                g_col = (one / sb.w_col) / epsc
-                g_ikp = (one / sb.w_ikp) / epsc  # (procs, D, 2, Cl)
-                g_sel = np.where(
-                    sb.seg,
-                    g_ikp[:, :, 1][:, :, None, :],
-                    g_ikp[:, :, 0][:, :, None, :],
-                ).astype(npdt)
-                dinv = (one / (g_row + g_sel + g_col)).astype(npdt)
-                work |= {
-                    "g_row": put(g_row),
-                    "g_col": put(g_col),
-                    "g_sel": put(g_sel),
-                    "dinv": put(dinv),
-                }
-            else:
-                work |= {
-                    "w_row": put(sb.w_row),
-                    "w_col": put(sb.w_col),
-                    "w_ikp": put(sb.w_ikp),
-                }
-            self._work_dev.append(work)
+        self._work_dev = self._stage_buckets()
         self._pass_fn = self._jit_staged(self._one_pass)
+
+    def _stage_buckets(self) -> list[dict]:
+        """Static staging (DESIGN.md §4): the lane tables, folded
+        geometry, step masks and projection gains (or, with
+        ``fused=False``, the gathered weight slabs) are pass-invariant,
+        so they are built once and sharded on the device axis like the
+        dual slabs; the per-device scan body does no index math and no
+        weight gathers. In ``ParallelSolver``'s order: the lane tables
+        are put first (``repro.stage.put``), then each bucket's slabs are
+        built on the host (``repro.stage.slabs``) and put in turn, and
+        the last put span waits for every transfer. Each array goes from
+        the host straight to the devices, each device receiving only its
+        own shard."""
+        shard = NamedSharding(self.mesh, P(AXIS))
+        put = lambda a: jax.device_put(a, shard)
+        with obs.span("repro.stage.put"):
+            work = [
+                {key: put(getattr(bl, key))
+                 for key in ("i", "k", "sizes", "i2", "k2", "sizes2")}
+                | {"T": bl.T}
+                for bl in self.layout.buckets
+            ]
+        npdt = np.dtype(self.dtype)
+        with obs.span("repro.stage.slabs"):
+            stage = sched.build_static_stage(self.layout, self.p.w, npdt)
+        for wk, sb in zip(work, stage):
+            with obs.span("repro.stage.slabs"):
+                slabs = {"J": sb.J, "iN": sb.iN, "kN": sb.kN,
+                         "act": sb.active, "seg": sb.seg}
+                if self._fused_sweep:
+                    # Projection gains (DESIGN.md §4), staged with the
+                    # procs axis — the exact expressions of
+                    # ParallelSolver._stage_buckets, so the per-step math
+                    # is shared bit-for-bit with the single-device fused
+                    # path.
+                    one = npdt.type(1.0)
+                    epsc = npdt.type(self.p.eps)
+                    g_row = (one / sb.w_row) / epsc
+                    g_col = (one / sb.w_col) / epsc
+                    g_ikp = (one / sb.w_ikp) / epsc  # (procs, D, 2, Cl)
+                    g_sel = np.where(
+                        sb.seg,
+                        g_ikp[:, :, 1][:, :, None, :],
+                        g_ikp[:, :, 0][:, :, None, :],
+                    ).astype(npdt)
+                    dinv = (one / (g_row + g_sel + g_col)).astype(npdt)
+                    slabs |= {"g_row": g_row, "g_col": g_col,
+                              "g_sel": g_sel, "dinv": dinv}
+                else:
+                    slabs |= {"w_row": sb.w_row, "w_col": sb.w_col,
+                              "w_ikp": sb.w_ikp}
+            with obs.span("repro.stage.put"):
+                wk.update({key: put(a) for key, a in slabs.items()})
+        with obs.span("repro.stage.put"):
+            jax.block_until_ready(work)
+        return work
 
     # ------------------------------------------------------------------ state
     def init_state(self) -> ShardedState:
@@ -264,6 +276,12 @@ class ShardedSolver(SolverRuntime):
         # shard_map keeps the device axis with local extent 1 — drop it.
         yd_b = yd_b[0]
         work = {key: val[0] for key, val in work.items()}
+        # Bytes this bucket program all-reduces: per diagonal, the (n, n)
+        # delta matrix, or the packed (2T + 7, p, Cl) segments.
+        D, Cl = yd_b.shape[0], yd_b.shape[-1]
+        cells = (x.size if self.delta_mode == "psum"
+                 else (2 * T + 7) * self.nproc * Cl)
+        obs.count("repro.shard.merge.bytes", D * cells * x.dtype.itemsize)
 
         def diag_body(x, inp):
             w, yslab = inp  # per-diagonal slices of work arrays + dual slab
@@ -283,7 +301,9 @@ class ShardedSolver(SolverRuntime):
                     w["g_row"], w["g_col"], w["g_sel"], w["dinv"],
                     active, seg, unroll=self.sweep_unroll,
                 )
-                return x + jax.lax.psum(delta, AXIS), new_yslab
+                with jax.named_scope("repro.shard.merge"):
+                    x = x + jax.lax.psum(delta, AXIS)
+                return x, new_yslab
             get = lambda a, idx, fill: a.at[idx].get(mode="fill", fill_value=fill)
             rowb = get(x, (iN, J), 0.0)
             colb = get(x, (J, kN), 0.0)
@@ -319,7 +339,8 @@ class ShardedSolver(SolverRuntime):
                 delta = add(delta, (i1, k1), d_ik1)
                 delta = add(delta, (i2, k2), d_ik2)
                 # conflict-free ⇒ exact merge (disjoint supports), no average
-                x = x + jax.lax.psum(delta, AXIS)
+                with jax.named_scope("repro.shard.merge"):
+                    x = x + jax.lax.psum(delta, AXIS)
             else:
                 # §Perf H3: exchange only the TOUCHED segments in schedule
                 # layout — payload per diagonal is p·(2·T·Cl + 7·Cl) floats
@@ -339,7 +360,9 @@ class ShardedSolver(SolverRuntime):
                 pack = jax.lax.dynamic_update_slice(
                     pack, mine[:, None, :], (0, rank, 0)
                 )
-                pack = jax.lax.psum(pack, AXIS)  # invariant, compact payload
+                with jax.named_scope("repro.shard.merge"):
+                    # invariant, compact payload
+                    pack = jax.lax.psum(pack, AXIS)
                 # every device reconstructs all p lane groups: flatten the
                 # (p, Cl) lane tables and reuse the shared folded geometry
                 g_row = jnp.moveaxis(pack[:T_], 1, 0)        # (p, T, Cl)

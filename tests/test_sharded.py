@@ -301,3 +301,31 @@ def test_sharded_fused_8_devices_subprocess():
     )
     assert out.returncode == 0, out.stderr[-3000:]
     assert "FUSED8_OK" in out.stdout
+
+
+@pytest.mark.parametrize("mode", ["psum", "packed"])
+def test_sharded_stage_spans_and_merge_bytes(mode):
+    """The constructor records the stage spans in ParallelSolver's order
+    (layout once, slabs 1 + buckets, put 2 + buckets); each traced bucket
+    program adds the bytes it all-reduces to ``repro.shard.merge.bytes``:
+    per diagonal the (n, n) f32 delta in psum mode, the (2T + 7, p, Cl)
+    packed segments in packed mode."""
+    from repro import obs
+
+    n = 10
+    obs.reset()
+    solver = ShardedSolver(_problem(n, seed=3, cc=True), _mesh1(),
+                           num_buckets=3, use_kernel=mode == "psum",
+                           delta_mode=mode)
+    nb = len(solver.layout.buckets)
+    assert {k: v["n"] for k, v in obs.snapshot().items()} == {
+        "repro.stage.layout": 1, "repro.stage.slabs": 1 + nb,
+        "repro.stage.put": 2 + nb}
+    solver.run_until(tol=0.0, max_passes=2, check_every=1)
+    per_diag = [
+        n * n if mode == "psum" else (2 * bl.T + 7) * bl.procs * bl.lanes
+        for bl in solver.layout.buckets
+    ]
+    want = sum(bl.num_diagonals * c * 4
+               for bl, c in zip(solver.layout.buckets, per_diag))
+    assert obs.snapshot()["repro.shard.merge.bytes"]["n"] == want
