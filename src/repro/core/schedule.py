@@ -30,6 +30,7 @@ import numpy as np
 __all__ = [
     "BucketLayout",
     "Diagonal",
+    "LANE_DEAL",
     "Schedule",
     "ScheduleLayout",
     "StageBucket",
@@ -116,9 +117,17 @@ def enumerate_triplets(n: int) -> np.ndarray:
     return out
 
 
-def device_assignment(num_sets: int, p: int) -> np.ndarray:
-    """Paper Fig. 3: the r-th set on a diagonal goes to processor r mod p."""
-    return np.arange(num_sets, dtype=np.int64) % p
+# How ``build_layout`` deals a diagonal's lanes over devices. Dual slabs
+# saved with procs > 1 are only valid under the deal that wrote them, so
+# checkpoints record this name (launch/solve.py).
+LANE_DEAL = "contiguous"
+
+
+def device_assignment(num_lanes: int, lanes: int) -> np.ndarray:
+    """Device of each folded lane of a diagonal: device r holds the
+    contiguous run of lanes r·Cl … r·Cl + Cl − 1, Cl = ``lanes`` per
+    device."""
+    return np.arange(num_lanes, dtype=np.int64) // lanes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -201,7 +210,14 @@ def build_schedule(n: int, pad_sets_to: int | None = None) -> Schedule:
 # 2: long (j,k) apex i). The slab slice for one diagonal is addressed by the
 # ``lax.scan`` step index directly — no gather, no scatter. ``procs`` is the
 # device count (1 for the single-device solver); lane f of a diagonal maps to
-# (device f % procs, slot f // procs), the paper's Fig. 3 assignment.
+# (device f // Cl, slot f % Cl), so each device holds a contiguous run of the
+# diagonal's lanes and its operands are columns of dense blocks of X at
+# per-diagonal offsets (the band engine, DESIGN.md §10). Any partition of a
+# diagonal's lanes is exact: its sets are mutually conflict-free, so each
+# cell of X is updated on exactly one device and the merge adds +0.0 from
+# the others. The paper's Fig. 3 round robin (set r to processor r mod p)
+# is one such partition, chosen there for balance; folded lanes have
+# near-uniform height, so contiguous runs balance as well.
 #
 # **Lane folding**: the sets of a diagonal have sizes s, s-2, s-4, ... — a
 # rectangular (T, C) layout would waste ~half its area on the triangular
@@ -266,6 +282,12 @@ class BucketLayout:
     @property
     def slab_size(self) -> int:
         return int(np.prod(self.slab_shape))
+
+    @property
+    def first_sets(self) -> np.ndarray:
+        """(D, 2) int32: each diagonal's first set (x, z), held by lane 0
+        of device 0 (a device's own lanes need not hold it)."""
+        return np.stack([self.i[0, :, 0], self.k[0, :, 0]], axis=-1)
 
     @property
     def num_duals(self) -> int:
@@ -351,7 +373,8 @@ def build_layout(
     Args:
       n: number of points.
       num_buckets: contiguous diagonal buckets (bounds scan padding waste).
-      procs: device count; lanes are dealt round-robin (paper Fig. 3).
+      procs: device count; each device holds a contiguous run of every
+        diagonal's lanes (``device_assignment``).
       pad_sets_to: round the lane dimension up to a multiple (TPU alignment).
     """
     diags = diagonal_list(n)
@@ -370,8 +393,8 @@ def build_layout(
             for name in ("i", "k", "i2", "k2")
         }
         for r, (iA, kA, iB, kB) in enumerate(folds):
-            f = np.arange(len(iA))
-            dev, slot = f % procs, f // procs
+            dev = device_assignment(len(iA), Cl)
+            slot = np.arange(len(iA)) - dev * Cl
             arrs["i"][dev, r, slot] = iA
             arrs["k"][dev, r, slot] = kA
             arrs["i2"][dev, r, slot] = iB

@@ -2,9 +2,16 @@
 
 Maps the paper's multithreaded execution model onto a TPU/CPU device mesh:
 
-  * **Set assignment** (paper Fig. 3): the r-th set on each diagonal goes to
-    device ``r mod p``. We materialize this as per-device work arrays of shape
-    ``(p, D, Cl)`` (Cl = ceil(Cmax/p)) so the shard_map simply splits axis 0.
+  * **Set assignment** (paper Fig. 3 deals the r-th set of a diagonal to
+    processor ``r mod p``): here each device holds a contiguous run of a
+    diagonal's folded lanes, device r lanes ``r·Cl … r·Cl + Cl − 1``
+    (Cl = ceil(lanes/p) over the bucket), materialized as per-device work
+    arrays of shape ``(p, D, Cl)`` so the shard_map simply splits axis 0.
+    Any partition of a diagonal's lanes is exact, its sets being mutually
+    conflict-free; the round robin was the paper's choice for balance,
+    which folded lanes of near-uniform height give here too. A contiguous
+    run makes a device's operands columns of dense blocks of X, which the
+    kernel path reads and writes without element gathers (DESIGN.md §10).
   * **Per-device dual arrays** (paper §III.D): every triplet is visited by the
     same device in the same order each pass, so its three duals live in a
     *schedule-native* slab ``(p, D, 3, T, Cl)`` sharded on axis 0 — the exact
@@ -187,7 +194,9 @@ class ShardedSolver(SolverRuntime):
             work = [
                 {key: put(getattr(bl, key))
                  for key in ("i", "k", "sizes", "i2", "k2", "sizes2")}
-                | {"T": bl.T}
+                | {"first": put(np.ascontiguousarray(np.broadcast_to(
+                    bl.first_sets, (bl.procs, bl.num_diagonals, 2)))),
+                   "T": bl.T}
                 for bl in self.layout.buckets
             ]
         npdt = np.dtype(self.dtype)
@@ -248,7 +257,7 @@ class ShardedSolver(SolverRuntime):
     def _fused_sweep(self) -> bool:
         """True when the per-device sweep runs on staged projection gains —
         the jnp ``ref.fused_diag_sweep`` body, or the gen-3 megakernel in
-        delta-output mode when ``use_kernel`` (both consume the same
+        sharded mode when ``use_kernel`` (both consume the same
         staged gains; DESIGN.md §10). Only the legacy baseline
         (``fused=False``) keeps the runtime-weight slab contract."""
         return self.fused
@@ -283,27 +292,23 @@ class ShardedSolver(SolverRuntime):
                  else (2 * T + 7) * self.nproc * Cl)
         obs.count("repro.shard.merge.bytes", D * cells * x.dtype.itemsize)
 
+        if fused and self.use_kernel:
+            # Gen-3 megakernel over the whole bucket (DESIGN.md §10): the
+            # band engine reads this device's lanes as dense blocks of a
+            # padded replica of X, and each diagonal's act-masked delta
+            # matrix is all-reduced and added to it inside the bucket
+            # program — bitwise-equal to the scatter construction below.
+            x, new_yd = kops.fused_bucket_pass_sharded(
+                x, yd_b, work, AXIS, unroll=self.sweep_unroll
+            )
+            return x, new_yd[None]
+
         def diag_body(x, inp):
             w, yslab = inp  # per-diagonal slices of work arrays + dual slab
             i1, k1, s1 = w["i"], w["k"], w["sizes"]
             i2, k2, s2 = w["i2"], w["k2"], w["sizes2"]
             J, iN, kN = w["J"], w["iN"], w["kN"]
             active, seg = w["act"], w["seg"]
-            if fused and self.use_kernel:
-                # Gen-3 megakernel, delta-output mode (DESIGN.md §10): X
-                # stays read-only and the kernel emits this device's
-                # act-masked delta matrix directly — bitwise-equal to the
-                # scatter construction below, so the psum merge is exact.
-                delta, new_yslab = kops.fused_diag_pass_delta(
-                    x, yslab,
-                    jnp.stack([i1, k1, s1, i2, k2, s2]),
-                    jnp.stack([J, iN, kN]),
-                    w["g_row"], w["g_col"], w["g_sel"], w["dinv"],
-                    active, seg, unroll=self.sweep_unroll,
-                )
-                with jax.named_scope("repro.shard.merge"):
-                    x = x + jax.lax.psum(delta, AXIS)
-                return x, new_yslab
             get = lambda a, idx, fill: a.at[idx].get(mode="fill", fill_value=fill)
             rowb = get(x, (iN, J), 0.0)
             colb = get(x, (J, kN), 0.0)

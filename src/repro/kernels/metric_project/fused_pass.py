@@ -15,22 +15,26 @@ launch. Gen-3 changes the contract, not the math:
     weights-as-operands re-partitioning applied to the kernel itself).
     Only the lane tables, the ``seg`` masks and the folded geometry — pure
     functions of the bucket shape — stay shared.
-  * **Delta-output mode** (``out_delta=True``, single diagonal): instead of
-    updating X in place the engine scatters the act-masked deltas into a
-    zero buffer — exactly the per-device delta matrix the sharded solver
-    psum-merges per diagonal (bitwise-equal to the jnp fused path's
-    scatter, because both scatter the same ``where(act, new - old, 0)``
-    values into zeros).
+  * **Sharded mode** (``axis_name`` given, inside ``shard_map``): the
+    lanes are this device's contiguous share of each diagonal
+    (``schedule.build_layout`` deals device r the lanes r·C … r·C + C − 1).
+    Instead of updating X in place the engine adds the act-masked deltas
+    into a zero buffer, which is all-reduced over the axis after each
+    diagonal and added to X — exactly the per-device delta matrix and
+    merge of the jnp sharded path (bitwise-equal, because both write the
+    same ``where(act, new - old, 0)`` values into zeros, and the other
+    devices add +0.0 to every cell, the diagonal's sets being
+    conflict-free).
 
 Both modes walk the bucket's diagonals in one loop, carrying
-the dual slab and updating it in place. Per diagonal they gather the
+the dual slab and updating it in place. Per diagonal they read the
 folded X row/column/carry slices in XLA, sweep them, and add the
-act-masked deltas back in XLA. A single-device bucket (lane f holds set
-f of its diagonal) reads and writes X as dense blocks at offsets fixed
-per diagonal, skewed by a static shear (the band engine); the sharded
-delta path, whose lanes are dealt over devices, gathers and scatters by
-the element index tables of ``ref.fused_bucket_pass_ref`` (the index
-engine). The two modes differ only in the sweep:
+act-masked deltas back in XLA. The band engine reads and writes X as
+dense blocks at offsets fixed per diagonal (moved by the device's first
+lane in sharded mode), skewed by a static shear; the tiled vector engine
+gathers and scatters by the element index tables of
+``ref.fused_bucket_pass_ref`` (the index engine). The engines differ only
+in the sweep:
 
   * ``mode="tpu"`` (TPU production, the only engine compiled with
     ``interpret=False``): the sweep is a Pallas kernel (``_sweep_kernel``)
@@ -245,8 +249,7 @@ def _scatter_diag(outb, lane, geo, ab, rowb, colb, xikp, nrow, ncol, nxikp):
 
 def _index_engine(lane, geo):
     """Gather and scatter of one diagonal by the element index tables
-    ``J/iN/kN``, for any lane deal (the sharded delta path, the tiled
-    vector engine)."""
+    ``J/iN/kN`` (the tiled vector engine)."""
 
     def gather(xv):
         return jax.vmap(lambda xb: _gather_diag(xb, lane, geo))(xv)
@@ -259,8 +262,8 @@ def _index_engine(lane, geo):
 
 
 # ---------------------------------------------------------------------------
-# Band engine (DESIGN.md §10): the gathers and scatters of a ``procs = 1``
-# diagonal as dense blocks of X.
+# Band engine (DESIGN.md §10): the gathers and scatters of a diagonal's
+# lanes as dense blocks of X.
 #
 # Diagonal d holds the sets S_{x+c, z-c}, c < C_d, and its lane f holds set
 # f (segment A) and set C_d-1-f (segment B). With r0 = x - s + C_d + 1,
@@ -279,12 +282,20 @@ def _index_engine(lane, geo):
 # bucket's lane count. A block row or column outside a lane's set holds
 # other cells of X (or padding): the sweep reads them only at steps
 # ``act`` masks, and the write-back adds an exact +0.0 there.
+#
+# A device that holds lanes f0 … f0+F-1 of the diagonal (sharded mode,
+# f0 = rank·F) reads the same blocks moved f0 steps along their own
+# diagonals: rows A and columns B down and right, row B up and right,
+# column A down and left. The offsets come from the diagonal's first set
+# (x, z), which the device need not hold, and f0.
 # ---------------------------------------------------------------------------
 
 
 def _band_pads(n: int, T: int, F: int):
     """(top, bottom, left, right) padding of X under which no block of any
-    diagonal of a (T, F) bucket leaves the padded matrix.
+    diagonal of a bucket of height T and F lanes leaves the padded matrix;
+    in sharded mode F counts every device's lanes, and the blocks of each
+    device's run of them stay inside too.
 
     ``lax.dynamic_slice`` clamps a start that would run off the end, which
     moves the whole window. The bounds follow from 0 <= x, z <= n-1,
@@ -314,30 +325,38 @@ def _shear(a, sign: int):
     return a
 
 
-def _band_blocks(x, z, T: int, F: int):
-    """The blocks of the diagonal whose first set is S_{x,z}: name ->
-    (row, column) of its corner in X, name -> its (rows, columns), and the
-    row h of the sheared row-B block that holds the B carries."""
+def _band_blocks(x, z, T: int, F: int, f0=0):
+    """The blocks of lanes f0 … f0+F-1 of the diagonal whose first set is
+    S_{x,z}: name -> (row, column) of its corner in X, name -> its (rows,
+    columns), and the row h of the sheared row-B block that holds the B
+    carries."""
     s = z - x
     c = (s - 2) // 2 + 1
     r0 = x - s + c + 1
     at = {"row_a": (x, x + 1), "row_b": (x + c - F, r0),
           "col_a": (x, z - F + 1), "col_b": (r0, z - c + 1)}
+    if not (isinstance(f0, int) and f0 == 0):  # a static 0 adds no op
+        step = {"row_a": (1, 1), "row_b": (-1, 1),
+                "col_a": (1, -1), "col_b": (1, 1)}
+        at = {k: (r + step[k][0] * f0, col + step[k][1] * f0)
+              for k, (r, col) in at.items()}
     size = {"row_a": (F, T), "row_b": (F, T + F),
             "col_a": (T + 1, F), "col_b": (T + F, F)}
     return at, size, 2 * (s - c)
 
 
-def _band_engine(lane, segv, pads, transpose):
-    """Gather and scatter of one diagonal of a ``procs = 1`` bucket as
-    dense blocks of the padded batch ``xp`` (B, n + pads, n + pads); see
-    the table above. Nothing here is indexed per element. ``pads`` is the
-    (top, left) padding; ``transpose`` swaps the last two axes of a
-    (B, R, C) array."""
+def _band_engine(lane, segv, pads, transpose, first=None, f0=0):
+    """Gather, scatter and clear of one diagonal's lanes as dense blocks
+    of a padded batch ``xp`` (B, n + pads, n + pads); see the table above.
+    Nothing here is indexed per element. ``pads`` is the (top, left)
+    padding; ``transpose`` swaps the last two axes of a (B, R, C) array.
+    The lanes are f0 … f0+F-1 of the diagonal whose first set (x, z) is
+    ``first``; None reads it from lane 0 (f0 = 0)."""
     top, left = pads
     T, F = segv.shape
     i1, k1, s1, _, _, s2 = lane
-    at, size, h = _band_blocks(i1[0], k1[0], T, F)
+    x, z = (i1[0], k1[0]) if first is None else (first[0], first[1])
+    at, size, h = _band_blocks(x, z, T, F, f0)
     tr = transpose
     ident = lambda a: a
     # block -> its (R, F) frame (lane f in column f), and back
@@ -392,15 +411,22 @@ def _band_engine(lane, segv, pads, transpose):
             xp = jax.lax.dynamic_update_slice(xp, blk + u, start(k))
         return xp
 
-    return gather, scatter
+    def clear(xp):
+        """``xp`` with this diagonal's blocks set to zero."""
+        for k in at:
+            zero = jnp.zeros((xp.shape[0],) + size[k], xp.dtype)
+            xp = jax.lax.dynamic_update_slice(xp, zero, start(k))
+        return xp
+
+    return gather, scatter, clear
 
 
 def _diag_step(xv, out, engine, segv, yv, grow, gcol, gsel, dinv, actv,
                sweep):
     """One diagonal for B instances: the engine's gather, ``sweep``, the
     engine's scatter. ``xv`` is the gather source, ``out`` the scatter
-    target (the same values in in-place mode; zeros in delta mode)."""
-    gather, scatter = engine
+    target (the same values in in-place mode; zeros in sharded mode)."""
+    gather, scatter = engine[:2]
     with jax.named_scope("repro.bucket.gather"):
         rowb, colb, xikp = gather(xv)
     with jax.named_scope("repro.bucket.sweep"):
@@ -413,38 +439,46 @@ def _diag_step(xv, out, engine, segv, yv, grow, gcol, gsel, dinv, actv,
 
 
 def _bucket_loop(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
-                 geom, *, sweep, transpose, out_delta):
+                 first, *, sweep, transpose, axis_name):
     """One loop over the bucket's diagonals, each a ``_diag_step`` on the
-    whole batch — the bucket program of both sweeps. The dual slab is
-    carried and updated in place, one diagonal at a time.
+    whole batch through the band engine — the bucket program of both
+    sweeps. The dual slab is carried and updated in place, one diagonal
+    at a time, and X is read from a padded copy that the loop carries
+    (padded once per bucket program, stripped at its end).
 
-    In-place mode reads and writes X by the band engine, on a padded copy
-    of X that the loop carries; delta mode (lanes dealt round-robin over
-    devices, so a device's lanes hold no contiguous sets) by the index
-    engine."""
+    In place (``axis_name`` None) each diagonal's deltas are added into
+    that copy. In sharded mode they are added into a padded zero buffer
+    that the loop carries too; its (n, n) interior is all-reduced over
+    ``axis_name`` and added to the interior of X's copy, and the blocks
+    just written are zeroed again. Per diagonal, the only whole-matrix
+    traffic besides the all-reduce is the interior read out for it and
+    X's interior read, added to and written in place: no padded matrix
+    is copied."""
     n = x.shape[1]
     D, T, C = seg.shape
     at = lambda a, ax, d: jax.lax.dynamic_index_in_dim(
         a, d, ax, keepdims=False
     )
-    if not out_delta:
-        top, bottom, left, right = _band_pads(n, T, C)
-        with jax.named_scope("repro.bucket.gather"):
-            x = jnp.pad(x, ((0, 0), (top, bottom), (left, right)))
+    f0, lanes_all = 0, C
+    if axis_name is not None:
+        f0 = jax.lax.axis_index(axis_name) * C
+        lanes_all = jax.lax.axis_size(axis_name) * C
+    top, bottom, left, right = _band_pads(n, T, lanes_all)
+    with jax.named_scope("repro.bucket.gather"):
+        x = jnp.pad(x, ((0, 0), (top, bottom), (left, right)))
+    inner = lambda a: a[:, top:top + n, left:left + n]
 
     @jax.named_scope("repro.bucket")
     def diag(d, carry):
-        out, y = carry
+        xp, y = carry[0], carry[-1]
         segv = at(seg, 0, d) != 0
         lane = at(lanes, 1, d)
-        if out_delta:
-            engine = _index_engine(lane, tuple(at(g, 0, d) for g in geom))
-        else:
-            engine = _band_engine(lane, segv, (top, left), transpose)
+        engine = _band_engine(
+            lane, segv, (top, left), transpose,
+            None if first is None else at(first, 0, d), f0,
+        )
         out, ny = _diag_step(
-            # Delta mode gathers from the pristine X (D == 1 by contract);
-            # in-place mode threads the iterate.
-            x if out_delta else out, out, engine,
+            xp, xp if axis_name is None else carry[1], engine,
             segv, at(y, 1, d), at(g_row, 1, d),
             at(g_col, 1, d), at(g_sel, 1, d), at(dinv, 1, d),
             # The barrier makes the diagonal's slice of ``act`` a value of
@@ -452,14 +486,24 @@ def _bucket_loop(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
             # bucket's act slab to another layout on every diagonal.
             jax.lax.optimization_barrier(at(act, 1, d)) != 0, sweep,
         )
-        return out, jax.lax.dynamic_update_index_in_dim(y, ny, d, 1)
+        ny = jax.lax.dynamic_update_index_in_dim(y, ny, d, 1)
+        if axis_name is None:
+            return out, ny
+        # ``out`` holds this device's deltas. The sets are conflict-free,
+        # so each cell's delta is non-zero on one device at most and the
+        # all-reduce is an exact merge.
+        with jax.named_scope("repro.shard.merge"):
+            merged = jax.lax.psum(inner(out), axis_name)
+            xp = jax.lax.dynamic_update_slice(
+                xp, inner(xp) + merged, (0, top, left))
+        return xp, engine[2](out), ny
 
-    out0 = jnp.zeros_like(x) if out_delta else x
-    nx, ny = jax.lax.fori_loop(0, D, diag, (out0, yslab))
-    if not out_delta:
-        with jax.named_scope("repro.bucket.scatter"):
-            nx = nx[:, top:top + n, left:left + n]
-    return nx, ny
+    carry = (x, yslab) if axis_name is None else (
+        x, jnp.zeros_like(x), yslab)
+    carry = jax.lax.fori_loop(0, D, diag, carry)
+    with jax.named_scope("repro.bucket.scatter"):
+        nx = inner(carry[0])
+    return nx, carry[-1]
 
 
 def _fused_kernel_vector(
@@ -473,32 +517,27 @@ def _fused_kernel_vector(
     act_ref,    # (B, 1, T, Cb) per-instance step mask
     seg_ref,    # (1, T, Cb) shared segment mask
     geom_ref,   # (3, 1, T, Cb) int32 folded geometry: J, iN, kN
-    ox_ref,     # (B, n, n) working buffer: X, or the delta matrices
+    ox_ref,     # (B, n, n) working buffer: X
     oy_ref,     # (B, 1, 3, T, Cb)
     *,
     block_c: int,
     unroll: int,
-    out_delta: bool,
 ):
     d = pl.program_id(1)
     cb = pl.program_id(2)
 
     @pl.when((d == 0) & (cb == 0))
     def _init_x():
-        ox_ref[...] = (
-            jnp.zeros(ox_ref.shape, ox_ref.dtype) if out_delta
-            else x_ref[...]
-        )
+        ox_ref[...] = x_ref[...]
 
     col0 = cb * block_c
     lane = jax.lax.dynamic_slice(
         lanes_ref[...], (jnp.int32(0), d, col0), (6, 1, block_c)
     ).reshape(6, block_c)
-    xv = x_ref[...] if out_delta else ox_ref[...]
-    base = ox_ref[...] if out_delta else xv
+    xv = ox_ref[...]
     segv = seg_ref[0] != 0
     nxv, ny = _diag_step(
-        xv, base, _index_engine(lane, geom_ref[...][:, 0]), segv,
+        xv, xv, _index_engine(lane, geom_ref[...][:, 0]), segv,
         y_ref[...][:, 0], grow_ref[...][:, 0], gcol_ref[...][:, 0],
         gsel_ref[...][:, 0], dinv_ref[...][:, 0], act_ref[...][:, 0] != 0,
         _vector_sweep(unroll),
@@ -508,7 +547,7 @@ def _fused_kernel_vector(
 
 
 def _vector_tiled_pass(x, yslab, lanes, g_row, g_col, g_sel, dinv, act,
-                       seg, geom, *, block_c, unroll, out_delta):
+                       seg, geom, *, block_c, unroll):
     """Multi-block vector engine: a pallas grid of (diagonal, lane block)
     steps in interpret mode. The engine gathers/scatters by index with
     fill/drop semantics, so neither the lane axis nor X needs padding
@@ -542,10 +581,7 @@ def _vector_tiled_pass(x, yslab, lanes, g_row, g_col, g_sel, dinv, act,
         out_specs=[x_spec, y_spec],
     )
     nx, ny = pl.pallas_call(
-        functools.partial(
-            _fused_kernel_vector, block_c=bc, unroll=unroll,
-            out_delta=out_delta,
-        ),
+        functools.partial(_fused_kernel_vector, block_c=bc, unroll=unroll),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, n, n), x.dtype),
@@ -571,13 +607,14 @@ def fused_bucket_pass_pallas(
     act,
     seg,
     geom,
+    first=None,
     *,
     block_c: int = 128,
     interpret: bool = True,
     in_place: bool = False,
     mode: str = "vector",
     unroll: int = 4,
-    out_delta: bool = False,
+    axis_name: str | None = None,
 ):
     """One fused pass over a whole bucket of B instances; per instance it
     matches ``ref.fused_bucket_pass_ref`` bitwise on every live cell.
@@ -592,7 +629,11 @@ def fused_bucket_pass_pallas(
       act: (B, D, T, C) per-instance (ghost-aware) step masks.
       seg: (D, T, C) shared segment mask.
       geom: the folded geometry J, iN, kN — three (D, T, C) int32
-        arrays (a sequence, or one stacked (3, D, T, C) array).
+        arrays (a sequence, or one stacked (3, D, T, C) array); read by
+        the tiled vector engine alone.
+      first: (D, 2) int32 — the first set (x, z) of each diagonal, which
+        sharded mode needs (a device's lanes need not hold it); None
+        reads it from lane 0.
       block_c: lane block of the sweep (tpu mode: C itself, or a multiple
         of 128 when C is wider).
       mode: "tpu" (Pallas sweep kernel; the TPU engine) or "vector"
@@ -600,12 +641,13 @@ def fused_bucket_pass_pallas(
       unroll: unroll of the sequential-in-t sweep loop.
       in_place: alias the dual slab input→output inside the sweep kernel
         (enable under jit only, like the earlier generations).
-      out_delta: return the act-masked update deltas scattered into zeros
-        instead of the updated X (requires D == 1 — the sharded solver's
-        per-diagonal psum contract). X is read-only; duals still update.
+      axis_name: sharded mode, inside ``shard_map``: the lanes are this
+        device's contiguous share of each diagonal, lanes rank·C …
+        rank·C + C − 1 along ``axis_name``, and each diagonal's deltas are
+        all-reduced over it and added to X (the sharded solver's merge).
+        None: one device, X updated in place.
 
-    Returns (new_x, new_yslab) — (B, n, n) and (B, D, 3, T, C); new_x is
-    the delta matrix batch when ``out_delta``.
+    Returns (new_x, new_yslab) — (B, n, n) and (B, D, 3, T, C).
     """
     if mode not in ("tpu", "vector"):
         raise ValueError(f"unknown megakernel mode {mode!r}")
@@ -614,15 +656,16 @@ def fused_bucket_pass_pallas(
             "the vector engine runs in interpret mode only; compiled "
             "kernels use mode='tpu'"
         )
-    D, C = yslab.shape[1], yslab.shape[-1]
-    if out_delta and D != 1:
-        raise ValueError("out_delta requires a single-diagonal call (D=1)")
+    if axis_name is not None and first is None:
+        raise ValueError("sharded mode needs each diagonal's first set")
+    C = yslab.shape[-1]
     geom = tuple(g.astype(jnp.int32) for g in geom)
-    operands = (x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg, geom)
-    tiled = mode == "vector" and block_c < C
+    operands = (x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg)
+    # The tiled vector grid updates X in place, so the vector engine
+    # sweeps sharded lanes as one block.
+    tiled = mode == "vector" and block_c < C and axis_name is None
     # Counted once per trace of a bucket program: which engine it lowered to.
-    obs.count("repro.bucket.engine."
-              + ("index" if out_delta or tiled else "band"))
+    obs.count("repro.bucket.engine." + ("index" if tiled else "band"))
     transpose = lambda a: jnp.swapaxes(a, 1, 2)
     if mode == "tpu":
         sweep = functools.partial(
@@ -637,7 +680,7 @@ def fused_bucket_pass_pallas(
         sweep = _vector_sweep(unroll)
     else:
         return _vector_tiled_pass(
-            *operands, block_c=block_c, unroll=unroll, out_delta=out_delta
+            *operands, geom, block_c=block_c, unroll=unroll
         )
-    return _bucket_loop(*operands, sweep=sweep, transpose=transpose,
-                        out_delta=out_delta)
+    return _bucket_loop(*operands, first, sweep=sweep, transpose=transpose,
+                        axis_name=axis_name)

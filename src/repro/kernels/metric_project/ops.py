@@ -13,10 +13,10 @@ shape with per-instance data as runtime operands:
   * ``fused_bucket_pass_batched`` — serve batch path (``BatchedSolver``):
     a whole (B, ...) bucket in one bucket program; new instances or
     batches never recompile (gains/masks are operands).
-  * ``fused_diag_pass_delta``     — sharded path (``ShardedSolver``): one
-    diagonal per call in delta-output mode — the kernel returns the
-    act-masked update deltas scattered into zeros, exactly the per-device
-    delta matrix the solver psum-merges per diagonal.
+  * ``fused_bucket_pass_sharded`` — sharded path (``ShardedSolver``,
+    inside ``shard_map``): one device's contiguous share of every
+    diagonal's lanes, with the per-diagonal delta matrix all-reduced over
+    the mesh axis and added to the replicated X inside the bucket program.
 
 Test-oracle / benchmark-only entry points (first-generation per-diagonal
 kernel, ``metric_project.py`` — demoted from production routing in PR 6):
@@ -50,7 +50,7 @@ __all__ = [
     "diagonal_sweep_slab",
     "fused_bucket_pass",
     "fused_bucket_pass_batched",
-    "fused_diag_pass_delta",
+    "fused_bucket_pass_sharded",
     "set_default_block_c",
     "triangle_violation",
 ]
@@ -124,11 +124,11 @@ def diagonal_sweep_slab(rowb, colb, xikp, yslab, w_row, w_col, w_ikp, active,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_c", "interpret", "mode", "unroll", "out_delta"),
+    static_argnames=("block_c", "interpret", "mode", "unroll"),
     inline=True,
 )
 def _fused_pass_jit(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
-                    geom, block_c, interpret, mode, unroll, out_delta):
+                    geom, block_c, interpret, mode, unroll):
     # in_place (the sweep kernel writes its dual block over its input) is
     # safe here: under jit, XLA copies any donated buffer that is still
     # live in the caller. All per-instance data are operands, so every
@@ -143,7 +143,7 @@ def _fused_pass_jit(x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg,
     return fused_bucket_pass_pallas(
         x, yslab, lanes, g_row, g_col, g_sel, dinv, act, seg, geom,
         block_c=block_c, interpret=interpret, in_place=True, mode=mode,
-        unroll=unroll, out_delta=out_delta,
+        unroll=unroll,
     )
 
 
@@ -189,7 +189,7 @@ def fused_bucket_pass(x, yslab, bucket, block_c: int | None = None,
         one(bucket["g_row"]), one(bucket["g_col"]), one(bucket["g_sel"]),
         one(bucket["dinv"]), one(bucket["act"]), bucket["seg"], geom,
         block_c=bc, interpret=not _on_tpu(), mode=_kernel_mode(),
-        unroll=int(unroll), out_delta=False,
+        unroll=int(unroll),
     )
     return nx[0], ny[0]
 
@@ -217,35 +217,45 @@ def fused_bucket_pass_batched(x, yslab, geo, gains,
         x, yslab, lanes, gains["g_row"], gains["g_col"], gains["g_sel"],
         gains["dinv"], gains["act"], geo["seg"], geom,
         block_c=bc, interpret=not _on_tpu(), mode=_kernel_mode(),
-        unroll=int(unroll), out_delta=False,
+        unroll=int(unroll),
     )
 
 
-def fused_diag_pass_delta(x, yslab, lanes, geom, g_row, g_col, g_sel, dinv,
-                          act, seg, block_c: int | None = None,
-                          unroll: int = 4):
-    """One diagonal through the gen-3 megakernel in delta-output mode —
-    the sharded solver's per-device sweep (DESIGN.md §10): X is read-only
-    and the returned matrix holds the act-masked update deltas scattered
-    into zeros, bitwise-equal to the jnp fused path's per-diagonal delta
-    (``x_new = x + psum(delta)`` merges exactly; conflict-freedom makes
-    the supports disjoint across devices).
+def fused_bucket_pass_sharded(x, yslab, work, axis_name: str,
+                              block_c: int | None = None, unroll: int = 4):
+    """One device's share of a whole bucket through the gen-3 megakernel,
+    inside ``shard_map`` (DESIGN.md §10): the band engine reads and writes
+    X as dense blocks at this device's lane offset, and after each
+    diagonal the device's delta matrix is all-reduced over ``axis_name``
+    and added to X. Bitwise-equal to the jnp fused sharded path: both
+    write the same act-masked deltas into zeros, and the sets of a
+    diagonal are conflict-free, so the all-reduce adds +0.0 from every
+    other device.
 
     Args:
-      x: (n, n) replicated iterate.  yslab: (3, T, C) this diagonal's
-      dual slab.  lanes: (6, C) int32 lane tables.  geom: (3, T, C) int32
-      folded geometry (J, iN, kN).  g_*/dinv/act/seg: (T, C) staged
-      gains and masks.
+      x: (n, n) replicated iterate.  yslab: (D, 3, T, C) this device's
+      dual slab.  work: this device's staged bucket — lane tables
+      i/k/sizes/i2/k2/sizes2 (D, C), each diagonal's first set ``first``
+      (D, 2), geometry J/iN/kN, gains g_row/g_col/g_sel/dinv and masks
+      act/seg (D, T, C).
 
-    Returns (delta, new_yslab) — (n, n) and (3, T, C).
+    Returns (new_x, new_yslab).
+
+    Traced straight into the caller's ``shard_map`` program, not through
+    the shared ``_fused_pass_jit`` cache: the program holds a collective
+    over the caller's mesh, and its padding depends on the mesh's size.
     """
     bc = block_c or _DEFAULT_BLOCK_C
-    two = lambda a: a[None, None]
-    dx, ny = _fused_pass_jit(
-        x[None], yslab[None, None], lanes[:, None],
-        two(g_row), two(g_col), two(g_sel), two(dinv), two(act), seg[None],
-        geom[:, None],
-        block_c=bc, interpret=not _on_tpu(), mode=_kernel_mode(),
-        unroll=int(unroll), out_delta=True,
+    lanes = jnp.stack(
+        [work[key] for key in ("i", "k", "sizes", "i2", "k2", "sizes2")]
     )
-    return dx[0], ny[0, 0]
+    geom = (work["J"], work["iN"], work["kN"])
+    one = lambda a: a[None]
+    nx, ny = fused_bucket_pass_pallas(
+        x[None], yslab[None], lanes,
+        one(work["g_row"]), one(work["g_col"]), one(work["g_sel"]),
+        one(work["dinv"]), one(work["act"]), work["seg"], geom,
+        work["first"], block_c=bc, interpret=not _on_tpu(), in_place=True,
+        mode=_kernel_mode(), unroll=int(unroll), axis_name=axis_name,
+    )
+    return nx[0], ny[0]

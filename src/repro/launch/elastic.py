@@ -9,9 +9,9 @@ The framework's failure model for 1000+ node fleets:
     function of (seed, step) (train/data.py), so the token stream is
     identical post-restart.
 
-  * **Node failure (solver)**: the Dykstra schedule assigns the r-th set of
-    each diagonal to device ``r mod p`` (paper Fig. 3). Because the schedule
-    is deterministic in (n, p), restoring (X, F, dual slabs, pass counter)
+  * **Node failure (solver)**: the layout deals each device a contiguous
+    run of every diagonal's folded lanes (``schedule.device_assignment``).
+    Because the schedule is deterministic in (n, p), restoring (X, F, dual slabs, pass counter)
     under a NEW p re-shards duals exactly: ``reshard_duals`` applies the
     composed slab→slab permutation as one device-side gather, slabs left
     sharded. Convergence is unaffected — Dykstra tolerates any
@@ -25,8 +25,10 @@ The framework's failure model for 1000+ node fleets:
     ``train/checkpoint.py`` (CRC-verified restore + ``resume_or``); the
     deterministic fault source is ``serve/faults.py`` (DESIGN.md §11).
 
-  * **Stragglers**: the ``r mod p`` interleave is the paper's static balance;
-    diagonal bucketing bounds per-scan-step skew. For persistent stragglers
+  * **Stragglers**: folded lanes of near-uniform height give every device
+    the same (T, Cl) tile per diagonal, the paper's static balance (its
+    Fig. 3 gets it from an ``r mod p`` interleave); diagonal bucketing
+    bounds per-scan-step skew. For persistent stragglers
     the controller shrinks p at a pass boundary (this module's remesh) rather
     than blocking on the slow node — cheap because pass boundaries are
     frequent and checkpoints are async.

@@ -13,7 +13,9 @@ Solve-to-tolerance runs on the device-resident convergence engine
 a jitted ``lax.while_loop`` of ``--chunk``-pass chunks with the stopping
 pair (max violation, |duality gap|) tested on device — so the host is
 consulted once per window, not once per chunk. Checkpoint ``extra``
-carries the device metrics of the saved state.
+carries the device metrics of the saved state and the layout's lane deal
+(``schedule.LANE_DEAL``): sharded dual slabs are saved raw, so a resume
+refuses slabs dealt over several devices under another deal.
 
 Fault drills (DESIGN.md §11): ``--inject "kind@site:at[:k=v,..];.."`` or
 ``--fault-seed N`` arm a deterministic ``FaultInjector`` threaded through
@@ -34,13 +36,33 @@ import time
 import jax
 import numpy as np
 
-from repro.core import problems, rounding
+from repro.core import problems, rounding, schedule as sched
 from repro.core.parallel_dykstra import ParallelSolver
 from repro.core.sharded_dykstra import ShardedSolver
 from repro.graphs import generators, io as gio, jaccard
 from repro.launch import elastic, mesh as mesh_lib
 from repro.launch.compile_cache import use_compile_cache
 from repro.train import checkpoint as ckpt_lib
+
+
+def check_lane_deal(procs: int):
+    """A ``resume_or`` check: dual slabs dealt over ``procs`` > 1 devices
+    resume only under the lane deal that wrote them. Checkpoints written
+    before the deal was recorded dealt lane f to device f mod procs."""
+
+    def check(manifest):
+        deal = manifest.get("extra", {}).get("lane_deal")
+        if procs > 1 and deal != sched.LANE_DEAL:
+            raise ckpt_lib.CheckpointError(
+                f"step {manifest.get('step')} holds dual slabs dealt over "
+                f"{procs} devices under lane deal "
+                f"{deal or 'f mod procs (untagged)'}, but this solver deals "
+                f"{sched.LANE_DEAL!r} runs: its duals would resume in the "
+                "wrong lanes. Resume it with the version that wrote it, or "
+                "start afresh in another directory."
+            )
+
+    return check
 
 
 def build_injector(args):
@@ -231,7 +253,9 @@ def main(argv=None):
         mgr = ckpt_lib.CheckpointManager(
             args.ckpt_dir, every=args.ckpt_every, faults=injector
         )
-        state, done = mgr.resume_or(state)
+        state, done = mgr.resume_or(
+            state, check=check_lane_deal(solver.nproc if args.sharded else 1)
+        )
         if done:
             print(f"resumed at pass {done}")
 
@@ -298,7 +322,8 @@ def main(argv=None):
             # state is rebound to the snapshot program's live alias; the
             # device→host transfer runs on the writer thread.
             _, state = mgr.maybe_save(
-                done, state, extra={"n": n, "eps": args.eps, **extra},
+                done, state, extra={"n": n, "eps": args.eps,
+                                    "lane_deal": sched.LANE_DEAL, **extra},
                 donate=True,
             )
         if info.get("diverged"):
@@ -321,7 +346,8 @@ def main(argv=None):
             # the cadence would skip the terminal state — force-save it
             # (satellite of DESIGN.md §11's recoverability contract).
             _, state = mgr.maybe_save(
-                done, state, extra={"n": n, "eps": args.eps, **extra},
+                done, state, extra={"n": n, "eps": args.eps,
+                                    "lane_deal": sched.LANE_DEAL, **extra},
                 force=True, donate=True,
             )
     if mgr:

@@ -395,14 +395,19 @@ class CheckpointManager:
                     os.path.join(self.dir, f"step_{s:08d}"), ignore_errors=True
                 )
 
-    def resume_or(self, init_tree):
+    def resume_or(self, init_tree, check=None):
         """Restore the newest *intact* retained step, walking back over
         corrupt ones; fall through to ``(init_tree, 0)`` when nothing
-        usable survives. Structure mismatches still raise."""
+        usable survives. Structure mismatches still raise, and so does
+        ``check``, called with the restored step's manifest before it is
+        accepted (a step it refuses is not corrupt, so walking back to an
+        older one cannot help)."""
         for s in reversed(_list_steps(self.dir)):
             try:
                 tree, manifest = restore(self.dir, init_tree, s, faults=self.faults)
             except CorruptCheckpointError:
                 continue
+            if check is not None:
+                check(manifest)
             return tree, manifest["step"]
         return init_tree, 0

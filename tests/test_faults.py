@@ -329,6 +329,61 @@ def test_kill_mid_save_previous_checkpoint_survives(tmp_path):
     assert step == 1 and tree["x"][0, 0] == 1.0
 
 
+_DEAL4_SCRIPT = textwrap.dedent(
+    """
+    import glob, json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    from repro.launch import solve
+    from repro.train import checkpoint as ckpt
+
+    d = {ckpt_dir!r}
+    argv = ["--n", "12", "--chunk", "1", "--sharded", "--ckpt-dir", d,
+            "--ckpt-every", "1"]
+    solve.main(argv + ["--passes", "2"])
+    manifests = sorted(glob.glob(os.path.join(d, "step_*", "manifest.json")))
+    assert manifests
+
+    def tag(deal):
+        for path in manifests:
+            with open(path) as fh:
+                m = json.load(fh)
+            assert m["extra"].get("lane_deal") in ("contiguous", None)
+            m["extra"].pop("lane_deal", None)
+            if deal:
+                m["extra"]["lane_deal"] = deal
+            with open(path, "w") as fh:
+                json.dump(m, fh)
+
+    tag(None)  # as a checkpoint written before the deal was recorded
+    try:
+        solve.main(argv + ["--passes", "3"])
+    except ckpt.CheckpointError as e:
+        print("REFUSED", e)
+    tag("contiguous")
+    solve.main(argv + ["--passes", "3"])
+    """
+)
+
+
+def test_sharded_resume_refuses_slabs_of_another_lane_deal(tmp_path):
+    """Sharded dual slabs are saved raw, so a checkpoint records the
+    layout's lane deal, and on four devices the launcher refuses to
+    resume one that lacks it (written under the f mod p deal, its duals
+    would land in the wrong lanes), saying why; tagged, it resumes."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _DEAL4_SCRIPT.format(ckpt_dir=str(tmp_path))],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    refused = [ln for ln in out.stdout.splitlines()
+               if ln.startswith("REFUSED")]
+    assert len(refused) == 1 and "lane deal" in refused[0]
+    assert "wrong lanes" in refused[0]
+    assert out.stdout.count("resumed at pass 2") == 1
+
+
 # --------------------------------------------- device-loss degrade-and-resume
 _CHAOS8_SCRIPT = textwrap.dedent(
     """

@@ -128,31 +128,119 @@ def test_megakernel_solo_bitwise_f64(x64, n, buckets):
     _assert_band_matches_index(_l2_problem(n, seed=21), np.float64, buckets)
 
 
-@given(n=st.integers(3, 80), nb=st.integers(1, 8))
-@settings(max_examples=30, deadline=None)
-def test_property_band_blocks_stay_inside_the_padding(n, nb):
+@given(n=st.integers(3, 80), nb=st.integers(1, 8), procs=st.integers(1, 4))
+@settings(max_examples=40, deadline=None)
+def test_property_band_blocks_stay_inside_the_padding(n, nb, procs):
     """``lax.dynamic_slice`` clamps a start that runs off the array, which
-    moves the whole window: every block of every diagonal of a
-    single-device bucket must lie inside X padded by ``_band_pads``."""
+    moves the whole window: every block of every diagonal, at every
+    device's lane offset (devices whose lanes on a short diagonal are all
+    padding included), must lie inside X padded by ``_band_pads`` sized
+    for all the devices' lanes."""
     from repro.kernels.metric_project import fused_pass
 
-    for bl in sched.build_layout(n, num_buckets=nb, procs=1).buckets:
+    for bl in sched.build_layout(n, num_buckets=nb, procs=procs).buckets:
         T, F = bl.T, bl.lanes
-        top, bottom, left, right = fused_pass._band_pads(n, T, F)
+        top, bottom, left, right = fused_pass._band_pads(n, T, procs * F)
         for r in range(bl.num_diagonals):
-            at, size, _ = fused_pass._band_blocks(
-                int(bl.i[0, r, 0]), int(bl.k[0, r, 0]), T, F)
-            for k, (row, col) in at.items():
-                rows, cols = size[k]
-                assert -top <= row and row + rows <= n + bottom, (k, r)
-                assert -left <= col and col + cols <= n + right, (k, r)
+            for dev in range(procs):
+                at, size, _ = fused_pass._band_blocks(
+                    int(bl.i[0, r, 0]), int(bl.k[0, r, 0]), T, F, dev * F)
+                for k, (row, col) in at.items():
+                    rows, cols = size[k]
+                    assert -top <= row and row + rows <= n + bottom, (k, r)
+                    assert -left <= col and col + cols <= n + right, (k, r)
+
+
+def _sharded_stage(n, procs, dtype, seed):
+    """One bucket of an L2 nearness problem dealt over ``procs`` devices:
+    the layout, an upper-triangular X, non-zero duals and the staged
+    gains of ``ShardedSolver``, all numpy."""
+    p = _l2_problem(n, seed=seed)
+    lay = sched.build_layout(n, num_buckets=1, procs=procs)
+    bl = lay.buckets[0]
+    sb = sched.build_static_stage(lay, p.w, dtype)[0]
+    one, eps = dtype(1.0), dtype(p.eps)
+    g_row, g_col = (one / sb.w_row) / eps, (one / sb.w_col) / eps
+    g_ikp = (one / sb.w_ikp) / eps
+    g_sel = np.where(sb.seg, g_ikp[:, :, 1][:, :, None, :],
+                     g_ikp[:, :, 0][:, :, None, :]).astype(dtype)
+    dinv = (one / (g_row + g_sel + g_col)).astype(dtype)
+    rng = np.random.default_rng(seed)
+    x = np.triu(rng.uniform(0, 1, (n, n)), 1).astype(dtype)
+    y = (rng.uniform(0, 0.2, bl.slab_shape) * sb.active[:, :, None]).astype(
+        dtype)
+    return bl, sb, (g_row, g_col, g_sel, dinv), x, y
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64],
+                         ids=["f32", "f64"])
+@pytest.mark.parametrize("procs", [2, 3, 4])
+@pytest.mark.parametrize("n", [5, 14, 23, 40])
+def test_sharded_band_delta_matches_index_engine(x64, n, procs, dtype):
+    """One diagonal of one device in sharded mode: the band engine at the
+    device's lane offset, reading X from its padded copy and adding the
+    act-masked deltas into a padded zero buffer, leaves in the interior
+    bitwise the delta matrix the index engine scatters into zeros, and
+    the same live duals; ``clear`` zeros the buffer again. Every diagonal
+    and device is checked, among them diagonals whose lane count is not a
+    multiple of ``procs`` and devices that hold only padding lanes."""
+    from repro.kernels.metric_project import fused_pass
+
+    bl, sb, gains, x, y = _sharded_stage(n, procs, dtype, seed=n + procs)
+    T, F = bl.T, bl.lanes
+    top, bottom, left, right = fused_pass._band_pads(n, T, procs * F)
+    sweep = fused_pass._vector_sweep(4)
+    swap = lambda a: jnp.swapaxes(a, 1, 2)
+    lanes = np.stack([bl.i, bl.k, bl.sizes, bl.i2, bl.k2, bl.sizes2])
+    geom = np.stack([sb.J, sb.iN, sb.kN])
+
+    def step(engine, xv, out, segv, ops):
+        yv, *gv, act = (a[None] for a in ops)
+        return fused_pass._diag_step(xv, out, engine, segv, yv, *gv, act,
+                                     sweep)
+
+    @jax.jit
+    def band(lane, first, f0, seg, ops):
+        xp = jnp.pad(jnp.asarray(x)[None], ((0, 0), (top, bottom),
+                                             (left, right)))
+        eng = fused_pass._band_engine(lane, seg, (top, left), swap, first,
+                                      f0)
+        out, ny = step(eng, xp, jnp.zeros_like(xp), seg, ops)
+        return out[:, top:top + n, left:left + n], ny, eng[2](out)
+
+    @jax.jit
+    def index(lane, geo, seg, ops):
+        eng = fused_pass._index_engine(lane, geo)
+        return step(eng, jnp.asarray(x)[None], jnp.zeros((1, n, n), dtype),
+                    seg, ops)
+
+    uneven = padding_only = 0
+    for r in range(bl.num_diagonals):
+        first = np.array([bl.i[0, r, 0], bl.k[0, r, 0]], np.int32)
+        lanes_d = int((bl.i[:, r] >= 0).sum())
+        uneven += lanes_d % procs != 0
+        for dev in range(procs):
+            padding_only += not (bl.i[dev, r] >= 0).any()
+            seg = sb.seg[dev, r]
+            ops = [a[dev, r] for a in (y, *gains, sb.active)]
+            db, yb, cleared = band(lanes[:, dev, r], first,
+                                   np.int32(dev * F), seg, ops)
+            di, yi = index(lanes[:, dev, r], geom[:, dev, r], seg, ops)
+            np.testing.assert_array_equal(np.asarray(db), np.asarray(di))
+            live = np.broadcast_to(sb.active[dev, r][None, None], yb.shape)
+            np.testing.assert_array_equal(np.asarray(yb)[live],
+                                          np.asarray(yi)[live])
+            assert not np.asarray(cleared).any()
+    assert uneven and (padding_only or procs == 2 and n == 5)
 
 
 def test_bucket_programs_count_their_engine():
     """Each bucket program counts, when traced, the engine it lowered to:
-    the band engine for a single-device bucket, the index engine for the
-    sharded delta path (one diagonal of lanes dealt over devices) and the
-    tiled vector engine."""
+    the band engine for a single-device bucket and for the sharded path
+    (a device's contiguous share of each diagonal's lanes, merged over a
+    mesh axis), the index engine for the tiled vector engine."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
     from repro import obs
     from repro.kernels.metric_project import fused_pass
 
@@ -169,15 +257,22 @@ def test_bucket_programs_count_their_engine():
     assert mid == {"band": before["band"] + 3, "index": before["index"]}
     b, d = solver._buckets[0], 0
     take = lambda *keys: jnp.stack([b[k][d] for k in keys])
-    fused_pass.fused_bucket_pass_pallas(
+    first = jnp.stack([b["i"][d:d + 1, 0], b["k"][d:d + 1, 0]], axis=-1)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("solver",))
+    sharded = jax.shard_map(
+        lambda *a: fused_pass.fused_bucket_pass_pallas(
+            *a, axis_name="solver"),
+        mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
+    )
+    sharded(
         st.x[None], st.yd[0][None, d:d + 1],
         take("i", "k", "s", "i2", "k2", "s2")[:, None],
         *(b[k][None, d:d + 1] for k in ("g_row", "g_col", "g_sel", "dinv",
                                           "act")),
-        b["seg"][d:d + 1], take("J", "iN", "kN")[:, None], out_delta=True,
+        b["seg"][d:d + 1], take("J", "iN", "kN")[:, None], first,
     )
     _engine_bucket_pass("vector-tiled", st.x, st.yd[0], b, b["act"])
-    assert n_of() == {"band": mid["band"], "index": mid["index"] + 2}
+    assert n_of() == {"band": mid["band"] + 1, "index": mid["index"] + 1}
 
 
 @pytest.mark.parametrize("sizes", [(12, 9, 12, None), (12, 7, None)],
@@ -314,13 +409,11 @@ def test_gen1_oracle_vs_gen3_parity(x64):
     x1 = sc(x1, (J, kN), jnp.where(act, nc1 - colb, 0))
     x1 = sc(x1, (i1, k1), jnp.where(s1 > 0, nx1[0] - xikp[0], 0))
     x1 = sc(x1, (i2, k2), jnp.where(s2 > 0, nx1[1] - xikp[1], 0))
-    dx, _ = ops.fused_diag_pass_delta(
-        x, yb[d], jnp.stack([i1, k1, s1, i2, k2, s2]),
-        jnp.stack([J, iN, kN]), b["g_row"][d], b["g_col"][d],
-        b["g_sel"][d], b["dinv"][d], act, seg
-    )
+    one = {key: v[d:d + 1] for key, v in b.items()
+           if hasattr(v, "shape") and v.shape[:1] == (yb.shape[0],)}
+    nx, _ = ops.fused_bucket_pass(x, yb[d:d + 1], one)
     np.testing.assert_allclose(
-        np.asarray(x1), np.asarray(x + dx), rtol=1e-13, atol=1e-14
+        np.asarray(x1), np.asarray(nx), rtol=1e-13, atol=1e-14
     )
 
 

@@ -78,13 +78,18 @@ def test_padded_schedule_consistent():
 
 
 def test_device_assignment_balance():
-    # paper Fig. 3: r mod p keeps per-processor triplet counts balanced
+    # contiguous runs of folded lanes keep per-device triplet counts
+    # balanced: paired lanes all have the same height
     n, p = 200, 16
     d = max(sched.diagonal_list(n), key=lambda d: d.num_sets)
-    asg = sched.device_assignment(d.num_sets, p)
+    iA, kA, iB, kB = sched._fold(d)
+    heights = (kA - iA - 1) + np.where(iB >= 0, kB - iB - 1, 0)
+    asg = sched.device_assignment(len(iA), -(-len(iA) // p))
+    assert np.all(np.diff(asg) >= 0) and asg.max() < p
     loads = np.zeros(p)
-    for r, sz in zip(asg, d.sizes):
-        loads[r] += sz
+    for r, h in zip(asg, heights):
+        loads[r] += h
+    assert loads.sum() == d.num_triplets
     assert loads.max() <= 1.5 * max(loads.mean(), 1.0)
 
 
@@ -121,16 +126,17 @@ def test_property_layout_roundtrip(n, nb, procs):
 
 
 def test_layout_matches_device_assignment():
-    """Folded-lane placement follows the paper's Fig. 3 r mod p rule: lane f
-    of a diagonal holds sets (f, C-1-f) and goes to device f mod p."""
+    """Folded-lane placement deals contiguous runs: lane f of a diagonal
+    holds sets (f, C-1-f) and goes to device f // Cl, slot f % Cl."""
     n, p = 14, 3
     lay = sched.build_layout(n, num_buckets=1, procs=p)
     diags = sched.diagonal_list(n)
     bl = lay.buckets[0]
+    cl = bl.lanes
     for r, d in enumerate(diags):
         C = d.num_sets
         for f in range((C + 1) // 2):
-            dev, slot = f % p, f // p
+            dev, slot = f // cl, f % cl
             assert bl.i[dev, r, slot] == d.i[f]
             assert bl.k[dev, r, slot] == d.k[f]
             assert bl.sizes[dev, r, slot] == d.k[f] - d.i[f] - 1
@@ -183,19 +189,24 @@ def test_slab_dims_match_layout(n, nb, procs):
 
 
 def _fold_is_contiguous(bl, dev: int = 0) -> bool:
-    """Whether every live slot f of device ``dev``, on every diagonal of
-    ``bl``, holds i1 = x+f, k1 = z-f, i2 = x+C-1-f, k2 = z-C+1+f, with
-    (x, z) the diagonal's first set and C its set count: the band
-    engine's precondition (fused_pass._band_engine)."""
+    """Whether every live slot l of device ``dev``, on every diagonal of
+    ``bl``, holds lane f = dev·Cl + l: i1 = x+f, k1 = z-f, i2 = x+C-1-f,
+    k2 = z-C+1+f, with (x, z) the diagonal's first set and C its set
+    count, and its live slots come first: the band engine's precondition
+    (fused_pass._band_engine, at lane offset dev·Cl)."""
     for r in range(bl.num_diagonals):
         i1, k1, i2, k2 = (a[dev, r] for a in (bl.i, bl.k, bl.i2, bl.k2))
-        f = np.flatnonzero(i1 >= 0)
+        live = np.flatnonzero(i1 >= 0)
+        if not np.array_equal(live, np.arange(len(live))):
+            return False
+        f = live + dev * bl.lanes
         x, z = int(bl.i[0, r, 0]), int(bl.k[0, r, 0])
         c = (z - x - 2) // 2 + 1
-        p = i2[f] >= 0  # paired lanes (an odd diagonal's middle set is not)
-        if not (np.array_equal(i1[f], x + f) and np.array_equal(k1[f], z - f)
-                and np.array_equal(i2[f][p], (x + c - 1 - f)[p])
-                and np.array_equal(k2[f][p], (z - c + 1 + f)[p])):
+        p = i2[live] >= 0  # paired lanes (an odd diagonal's middle set is not)
+        if not (np.array_equal(i1[live], x + f)
+                and np.array_equal(k1[live], z - f)
+                and np.array_equal(i2[live][p], (x + c - 1 - f)[p])
+                and np.array_equal(k2[live][p], (z - c + 1 + f)[p])):
             return False
     return True
 
@@ -210,10 +221,51 @@ def test_property_single_device_fold_is_contiguous(n, nb):
         assert _fold_is_contiguous(bl)
 
 
-def test_dealt_fold_is_not_contiguous():
-    """Dealt round robin over four devices, a device's slots hold lanes
-    f, f+4, ...: its sets are strided, which is why the sharded delta
-    path keeps the element-indexed engine."""
-    lay = sched.build_layout(24, num_buckets=2, procs=4)
-    assert not all(_fold_is_contiguous(bl, dev) for bl in lay.buckets
-                   for dev in range(4))
+@given(n=st.integers(3, 60), nb=st.integers(1, 6), procs=st.integers(2, 4))
+@settings(max_examples=25, deadline=None)
+def test_property_dealt_fold_is_contiguous(n, nb, procs):
+    """Dealt over devices, device r's slots hold lanes r·Cl, r·Cl+1, ...
+    of every diagonal, so its operands are columns of dense blocks of X
+    at per-diagonal offsets moved by r·Cl (the sharded band engine)."""
+    for bl in sched.build_layout(n, num_buckets=nb, procs=procs).buckets:
+        assert all(_fold_is_contiguous(bl, dev) for dev in range(procs))
+
+
+def _layout_by_formula(n, nb, procs):
+    """The lane tables of ``build_layout`` restated with the deal
+    ``dev = f % procs, slot = f // procs`` — with one device the same
+    tables as any deal, built independently of ``device_assignment``."""
+    diags = sched.diagonal_list(n)
+    out = []
+    for g in np.array_split(np.arange(len(diags)), nb):
+        if not len(g):
+            continue
+        folds = [sched._fold(diags[r]) for r in g]
+        cl = max(len(f[0]) for f in folds)
+        tab = {k: np.full((procs, len(g), -(-cl // procs)), -1, np.int32)
+               for k in ("i", "k", "i2", "k2")}
+        for r, fold in enumerate(folds):
+            f = np.arange(len(fold[0]))
+            for key, v in zip(("i", "k", "i2", "k2"), fold):
+                tab[key][f % procs, r, f // procs] = v
+        tab["sizes"] = np.where(tab["i"] >= 0, tab["k"] - tab["i"] - 1,
+                                0).astype(np.int32)
+        tab["sizes2"] = np.where(tab["i2"] >= 0, tab["k2"] - tab["i2"] - 1,
+                                 0).astype(np.int32)
+        out.append(tab)
+    return out
+
+
+@pytest.mark.parametrize("n,nb", [(3, 1), (9, 2), (24, 3), (41, 6)])
+def test_single_device_layout_is_unchanged(n, nb):
+    """With one device every lane table of ``build_layout`` is byte for
+    byte what the ``f % 1`` deal gives, so the single-device solvers,
+    the serve buckets and the sparse path see no change of deal."""
+    lay = sched.build_layout(n, num_buckets=nb, procs=1)
+    want = _layout_by_formula(n, nb, 1)
+    assert len(lay.buckets) == len(want)
+    for bl, tab in zip(lay.buckets, want):
+        for key, arr in tab.items():
+            got = getattr(bl, key)
+            assert got.dtype == arr.dtype and got.shape == arr.shape, key
+            assert got.tobytes() == arr.tobytes(), key

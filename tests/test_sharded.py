@@ -165,10 +165,10 @@ def test_sharded_fused_scan_matches_host_loop_bitwise():
 
 
 def test_sharded_kernel_matches_fused_jnp_bitwise():
-    """DESIGN.md §10: ``use_kernel=True`` routes every diagonal through
-    the gen-3 megakernel in delta-output mode — X and the dense dual
-    maps must equal the jnp fused path bitwise (the kernel emits the
-    same act-masked delta matrix the jnp path scatters)."""
+    """DESIGN.md §10: ``use_kernel=True`` routes every bucket through
+    the gen-3 megakernel in sharded mode — X and the dense dual maps
+    must equal the jnp fused path bitwise (the kernel merges the same
+    act-masked delta matrix the jnp path scatters)."""
     p = _problem(13, seed=0)
     a = ShardedSolver(p, _mesh1(), num_buckets=3).run(passes=2)
     b = ShardedSolver(p, _mesh1(), num_buckets=3, use_kernel=True).run(
@@ -219,9 +219,9 @@ _KERNEL8_SCRIPT = textwrap.dedent(
 @pytest.mark.multidevice
 def test_sharded_kernel_8_devices_subprocess():
     """True multi-device megakernel execution: on 8 host devices the
-    gen-3 delta-output kernel inside shard_map must equal the jnp fused
-    path bit-for-bit (per-device deltas scattered into zeros, one exact
-    psum per diagonal)."""
+    gen-3 kernel in sharded mode inside shard_map must equal the jnp
+    fused path bit-for-bit (per-device deltas written into zeros, one
+    exact psum per diagonal)."""
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(
@@ -230,6 +230,54 @@ def test_sharded_kernel_8_devices_subprocess():
     )
     assert out.returncode == 0, out.stderr[-3000:]
     assert "KERNEL8_OK" in out.stdout
+
+
+_KERNEL4_SCRIPT = textwrap.dedent(
+    """
+    import os, sys
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import numpy as np, jax
+    from jax.sharding import Mesh
+    from repro.core import problems, schedule as sched
+    from repro.core.sharded_dykstra import ShardedSolver
+
+    assert len(jax.devices()) == 4
+    n = int(sys.argv[1])
+    lanes = max(len(sched._fold(d)[0]) for d in sched.diagonal_list(n))
+    assert lanes % 4, lanes
+    rng = np.random.default_rng(n)
+    d = np.triu(rng.uniform(0, 1, (n, n)), k=1)
+    p = problems.metric_nearness_l2(d)
+    mesh = Mesh(np.array(jax.devices()), ("solver",))
+    a = ShardedSolver(p, mesh, num_buckets=3).run(passes=2)
+    b = ShardedSolver(p, mesh, num_buckets=3, use_kernel=True).run(passes=2)
+    np.testing.assert_array_equal(np.asarray(a.x), np.asarray(b.x))
+    probe = ShardedSolver(p, mesh, num_buckets=3)
+    np.testing.assert_array_equal(
+        probe.duals_to_dense(a), probe.duals_to_dense(b)
+    )
+    print("KERNEL4_OK")
+    """
+)
+
+
+@pytest.mark.multidevice
+@pytest.mark.parametrize("n", [13, 23])
+def test_sharded_kernel_4_devices_subprocess(n):
+    """On 4 host devices, as on the four chips of a v5e host: the kernel
+    path (the band engine at each device's lane offset, merged by one
+    all-reduce per diagonal) equals the jnp fused sharded path after 2
+    passes, X and the dense duals bitwise. The longest diagonal's lane
+    count is not a multiple of 4 (3 at n = 13, 6 at n = 23, where a
+    device holds only padding lanes)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run(
+        [sys.executable, "-c", _KERNEL4_SCRIPT, str(n)],
+        capture_output=True, text=True, env=env, timeout=600,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "KERNEL4_OK" in out.stdout
 
 
 def test_sharded_fused_baseline_matches_serial():

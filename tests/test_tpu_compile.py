@@ -14,7 +14,9 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import schedule as sched
 from repro.kernels.metric_project.fused_pass import fused_bucket_pass_pallas
@@ -27,13 +29,12 @@ HBM_BYTES = 16 * 2**30  # one TPU v5e chip (Google Cloud, "TPU v5e")
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A single-device sharding on a described v5e chip, with the
-    persistent compilation cache off (entries written for a described
-    chip cannot be read back without one)."""
+def v5e_2x2():
+    """A described v5e 2x2 (four chips), with the persistent compilation
+    cache off (entries written for a described chip cannot be read back
+    without one)."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     own_log_dir = "TPU_LOG_DIR" not in os.environ
     if own_log_dir:  # otherwise the TPU compiler logs under /tmp
@@ -46,11 +47,19 @@ def one_chip():
     cache_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", cache_on)
     cc.reset_cache()
     if own_log_dir:
         os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    """A single-device sharding on one chip of the described v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _compiled(fn, *args):
@@ -75,42 +84,45 @@ def _bucket(n, num_buckets=6, procs=1, which="first"):
     [
         (96, 1, 1, "first", False),     # solo bucket
         (128, 8, 1, "first", False),    # top serve rung, batch 8
-        (1024, 1, 4, "largest", True),  # sharded delta mode, one diagonal
+        (1024, 1, 4, "largest", True),  # sharded mode over four chips
         (768, 1, 1, "largest", False),  # solo smoke size, largest bucket
     ],
     ids=["n96-B1", "n128-B8", "n1024-p4-delta", "n768-largest"],
 )
-def test_fused_pass_compiles_for_v5e(one_chip, n, batch, procs, which,
-                                     delta):
-    """The bucket program compiles and fits one chip. A single-device
-    bucket reads and writes X as dense blocks (the band engine): no XLA
-    gather or scatter, and no custom fusion, which is how the TPU compiler
-    lowers them. The sharded delta path keeps the element-indexed engine."""
+def test_fused_pass_compiles_for_v5e(one_chip, v5e_2x2, n, batch, procs,
+                                     which, delta):
+    """The bucket program compiles and fits one chip. It reads and writes
+    X as dense blocks (the band engine): no XLA gather or scatter, and no
+    custom fusion, which is how the TPU compiler lowers them. The sharded
+    case is one chip's program of four (``shard_map`` over the described
+    2x2, the per-diagonal all-reduce included) at its share of the
+    largest n = 1024 bucket."""
     D, T, C = _bucket(n, procs=procs, which=which)
+    fn = functools.partial(fused_bucket_pass_pallas, mode="tpu",
+                           interpret=False, in_place=True)
+    where = one_chip
     if delta:
-        D = 1
+        mesh = Mesh(np.array(v5e_2x2.devices), ("solver",))
+        assert mesh.size == procs
+        where = NamedSharding(mesh, P())
+        fn = jax.shard_map(
+            functools.partial(fn, axis_name="solver"), mesh=mesh,
+            in_specs=P(), out_specs=P(), check_vma=False,
+        )
     f = lambda *s, dt=jnp.float32: jax.ShapeDtypeStruct(
-        s, dt, sharding=one_chip
+        s, dt, sharding=where
     )
     tc = f(batch, D, T, C)
     args = (
         f(batch, n, n), f(batch, D, 3, T, C), f(6, D, C, dt=jnp.int32),
         tc, tc, tc, tc, f(batch, D, T, C, dt=jnp.bool_),
         f(D, T, C, dt=jnp.bool_), f(3, D, T, C, dt=jnp.int32),
-    )
-    text = _compiled(
-        functools.partial(
-            fused_bucket_pass_pallas, mode="tpu", interpret=False,
-            in_place=True, out_delta=delta,
-        ),
-        *args,
-    ).as_text()
-    indexed = re.findall(r" (gather|scatter)\(", text)
+    ) + ((f(D, 2, dt=jnp.int32),) if delta else ())
+    text = _compiled(fn, *args).as_text()
+    assert not re.findall(r" (gather|scatter)\(", text)
+    assert "kind=kCustom" not in text
     if delta:
-        assert {"gather", "scatter"} <= set(indexed)
-    else:
-        assert not indexed
-        assert "kind=kCustom" not in text
+        assert re.search(r"all-reduce(-start)?\(", text)
 
 
 def test_violation_kernel_compiles_for_v5e(one_chip):
